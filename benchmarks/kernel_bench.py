@@ -365,7 +365,8 @@ def run_sharded(smoke: bool = False):
         f"block sets it, so jax was initialized before main() ran)")
     from repro.engine.dispatch import dequant_leaf, dispatch
     from repro.models.quantize import _pack_leaf
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     rng = np.random.default_rng(0)
     shapes = SMOKE_SHARDED_SHAPES if smoke else SHARDED_SHAPES
     smoke_labels = ("mip2q_p0.5", "dliq_p1.0", "dliq_p0.0")

@@ -295,7 +295,7 @@ def _read(ctx: _Ctx, atom) -> ErrVal:
 
 
 def _resolve(ctx: _Ctx, atom):
-    """Follow alias links (pjit inlining, cond branch operands)."""
+    """Follow alias links (jit inlining, cond branch operands)."""
     seen = 0
     while not _is_literal(atom) and atom in ctx.alias and seen < 64:
         atom = ctx.alias[atom]
@@ -1067,6 +1067,7 @@ _RULES = {
     "broadcast_in_dim": _rule_pass, "reshape": _rule_pass,
     "transpose": _rule_pass, "squeeze": _rule_pass,
     "expand_dims": _rule_pass, "rev": _rule_pass, "slice": _rule_pass,
+    "split": _rule_pass,
     "convert_element_type": _rule_pass, "copy": _rule_pass,
     "stop_gradient": _rule_pass, "dynamic_slice": _rule_pass,
     "real": _rule_pass, "imag": _rule_pass,
@@ -1113,7 +1114,7 @@ def _rule_pow(ctx, eqn, ins):
 
 _RULES["pow"] = _rule_pow
 
-_CALL_PRIMS = {"pjit": "jaxpr", "remat2": "jaxpr", "closed_call": "jaxpr",
+_CALL_PRIMS = {"jit": "jaxpr", "remat2": "jaxpr", "closed_call": "jaxpr",
                "custom_jvp_call": "call_jaxpr",
                "custom_vjp_call": "call_jaxpr",
                "custom_vjp_call_jaxpr": "fun_jaxpr"}

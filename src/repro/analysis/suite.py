@@ -32,6 +32,7 @@ import numpy as np
 from repro.analysis import dataflow, pallas_lint, recompile, registry_audit
 from repro.analysis.report import Report
 from repro.core.policy import StruMConfig
+from repro.launch.mesh import make_mesh
 
 __all__ = ["PASSES", "run_all", "tiny_model", "verify_local_apply",
            "verify_sharded_variants", "verify_cache_codecs",
@@ -92,13 +93,13 @@ def verify_local_apply(backend: Optional[str] = "interpret") -> Report:
 def _mesh_2d():
     n = len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((2, 2), ("data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((2, 2), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _mesh_1d():
     n = len(jax.devices())
-    return jax.make_mesh((2 if n >= 2 else 1,), ("data",))
+    return make_mesh((2 if n >= 2 else 1,), ("data",))
 
 
 def verify_sharded_variants(cfg: StruMConfig = _WCFG) -> Report:

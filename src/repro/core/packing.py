@@ -180,17 +180,18 @@ def _gather_compact(values: jnp.ndarray, mask: jnp.ndarray, count: int) -> jnp.n
 def _scatter_expand(payload: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     """Inverse of :func:`_gather_compact`: place payload back at mask slots.
 
-    Positions where ``mask`` is False get 0.  This is the vectorized
-    rank-gather decode used both by the jnp reference and (in unrolled form)
-    inside the Pallas kernel.
+    Positions where ``mask`` is False get 0.  One select per payload row
+    (``count <= w <= 32``) instead of a gather along the block axis: XLA
+    fuses the selects into one elementwise pass, where a TPU gather over
+    every weight element would dominate the dequantize.
     """
     nb, w, n = mask.shape
     count = payload.shape[1]
-    if count == 0:
-        return jnp.zeros((nb, w, n), payload.dtype)
+    out = jnp.zeros((nb, w, n), payload.dtype)
     rank = jnp.cumsum(mask, axis=1) - mask.astype(jnp.int32)
-    g = jnp.take_along_axis(payload, jnp.clip(rank, 0, count - 1), axis=1)
-    return jnp.where(mask, g, jnp.zeros_like(g))
+    for r in range(count):
+        out = jnp.where(mask & (rank == r), payload[:, r:r + 1, :], out)
+    return out
 
 
 def pack(qb: QuantizedBlocks, *, method: str, scale: jnp.ndarray, k_dim: int,

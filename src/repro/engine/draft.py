@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from repro.core import packing
 from repro.core.apply import path_name as _path_name
 from repro.kernels.ops import DRAFT_MODES, draft_field_set
-from repro.kernels.strum_matmul import _scatter_onehot, _unpack_mask
 
 __all__ = ["DraftPolicy", "build_draft_plan", "draft_dequant_packed",
            "draft_dequant_leaf", "draft_leaf_bytes", "draft_plan_bytes",
@@ -78,8 +77,8 @@ def draft_dequant_packed(packed: packing.PackedStruM, mode: str,
         raise ValueError(f"draft modes need high values to stream "
                          f"(n_low={packed.n_low} w={w})")
     if mode == "histream":
-        high = _unpack_mask(packed.mask, w)
-        vals = _scatter_onehot(packed.hi.astype(jnp.float32), high)
+        high = packing._unpack_bits_axis(packed.mask, w, axis=1)
+        vals = packing._scatter_expand(packed.hi.astype(jnp.float32), high)
     elif mode == "maskfree_p":
         hv = packed.hi.astype(jnp.float32)
         vals = jnp.concatenate(

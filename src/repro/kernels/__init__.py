@@ -1,4 +1,4 @@
-"""StruM Pallas TPU kernels (validated in interpret mode on CPU).
+"""StruM Pallas TPU kernels (Mosaic on TPU; interpret mode in CPU tests).
 
 strum_matmul — tiled matmul streaming compressed StruM weights, in-VMEM
 decode (the paper's accelerated PE, §IV-D.2, mapped to the TPU memory

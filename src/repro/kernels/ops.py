@@ -4,8 +4,8 @@ Handles tile-size selection, padding to tile multiples, payload-axis
 minimum sizes, and output dtype — callers just hand in activations and a
 :class:`~repro.core.packing.PackedStruM`.
 
-``interpret`` defaults to True off-TPU (the container validates kernels in
-interpret mode); on a real TPU backend the same code path lowers through
+``interpret`` defaults to True off-TPU (the CPU tests run the kernels in
+interpret mode); on a TPU backend the same code path lowers through
 Mosaic.  Set ``STRUM_INTERPRET=1`` (or ``0``) to force it either way, or
 override per call — the engine API (:mod:`repro.engine`) exposes this as
 ``backend="interpret"``.

@@ -35,9 +35,13 @@ to NEG_INF semantically and avoids NEG_INF − NEG_INF NaNs in the rescale.
 
 Grid: ``(B, KV, P)`` with the page axis innermost (``"arbitrary"``
 semantics — the online-softmax state is a cross-page reduction carry).
+The page table and ``n_valid`` are scalar-prefetch operands: they sit in
+SMEM, where the per-page ``pl.when`` branches read them.
 
-Validated in ``interpret=True`` mode against the dense attention oracle
-(tests/test_fused_attention.py).
+Matches the dense attention oracle in interpret mode on CPU
+(tests/test_fused_attention.py), compiles for TPU v5e at head_dim 128 /
+page_size 16 (tests/test_tpu_compile.py), and serves OLMo-1B's decode and
+chunked prefill in ``chip_smoke.py`` on a real chip.
 """
 from __future__ import annotations
 
@@ -48,14 +52,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ops import default_interpret
 from repro.kernels.strum_matmul import (
-    _decode_low,
     _decode_tile,
+    _decode_tile_maskfree,
     _mosaic_params,
     _scoped,
-    _unpack_fields,
 )
 
 __all__ = [
@@ -72,8 +76,10 @@ def _online_update(q_ref, ids_ref, nv_ref, acc_ref, m_ref, l_ref, decode_kv):
 
     ``decode_kv()`` returns the ``(page_size, hd)`` f32 K and V tiles; it is
     only invoked (via pl.when) for live pages, so decode work is skipped for
-    unassigned (-1) ids and for pages at/after the hot tail.
+    unassigned (-1) ids and for pages at/after the hot tail.  ``ids_ref``
+    (B, P) and ``nv_ref`` (B,) are scalar-prefetch operands in SMEM.
     """
+    b = pl.program_id(0)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -82,7 +88,7 @@ def _online_update(q_ref, ids_ref, nv_ref, acc_ref, m_ref, l_ref, decode_kv):
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    live = (ids_ref[0, 0] >= 0) & (p < nv_ref[0, 0])
+    live = (ids_ref[b, p] >= 0) & (p < nv_ref[b])
 
     @pl.when(live)
     def _fold():
@@ -101,8 +107,8 @@ def _online_update(q_ref, ids_ref, nv_ref, acc_ref, m_ref, l_ref, decode_kv):
         m_ref[0, 0] = m_new
 
 
-def _kernel(q_ref, km_ref, kh_ref, kl_ref, ks_ref, vm_ref, vh_ref, vl_ref,
-            vs_ref, ids_ref, nv_ref, acc_ref, m_ref, l_ref, *, w, n_low, q,
+def _kernel(ids_ref, nv_ref, q_ref, km_ref, kh_ref, kl_ref, ks_ref, vm_ref,
+            vh_ref, vl_ref, vs_ref, acc_ref, m_ref, l_ref, *, w, n_low, q,
             method):
     def decode_kv():
         kt = _decode_tile(km_ref[0, 0], kh_ref[0, 0], kl_ref[0, 0],
@@ -114,25 +120,14 @@ def _kernel(q_ref, km_ref, kh_ref, kl_ref, ks_ref, vm_ref, vh_ref, vl_ref,
     _online_update(q_ref, ids_ref, nv_ref, acc_ref, m_ref, l_ref, decode_kv)
 
 
-def _kernel_maskfree(q_ref, kl_ref, ks_ref, vl_ref, vs_ref, ids_ref, nv_ref,
+def _kernel_maskfree(ids_ref, nv_ref, q_ref, kl_ref, ks_ref, vl_ref, vs_ref,
                      acc_ref, m_ref, l_ref, *, w, q, method):
     def dec(lo_ref, s_ref):
-        codes = _unpack_fields(lo_ref[0, 0], w, q)             # (nb, w, hd)
-        vals = _decode_low(codes, method, q)
-        nb, _, hd = vals.shape
-        return vals.reshape(nb * w, hd) * s_ref[0, 0]
+        return _decode_tile_maskfree(lo_ref[0, 0], s_ref[0, 0], w=w, q=q,
+                                     method=method)
 
     _online_update(q_ref, ids_ref, nv_ref, acc_ref, m_ref, l_ref,
                    lambda: (dec(kl_ref, ks_ref), dec(vl_ref, vs_ref)))
-
-
-def _payload_specs(nb, rows_by_field, hd):
-    """(B, P, nb, rows, hd) payload field → one (page, kv-head) block."""
-    return [
-        pl.BlockSpec((1, 1, nb, max(rows, 1), hd),
-                     lambda b, g, p: (b, p, 0, 0, g))
-        for rows in rows_by_field
-    ]
 
 
 def _call(kern, q4, payload, page_ids, n_valid, nb, w, interpret):
@@ -142,35 +137,35 @@ def _call(kern, q4, payload, page_ids, n_valid, nb, w, interpret):
     payload   list of (B, P, nb, rows, hd) packed fields followed by their
               (B, P, 1, hd) f32 scales — already gathered per (slot, page)
     page_ids  (B, P) int32, original table entries (−1 = unassigned)
-    n_valid   (B, 1) int32, pages strictly before this index are sealed
+    n_valid   (B,) int32, pages strictly before this index are sealed
+
+    ``page_ids`` and ``n_valid`` are scalar-prefetch operands: they land in
+    SMEM ahead of the grid, where the kernel branches on them per page.
     """
     b, kv, r, hd = q4.shape
     pp = page_ids.shape[1]
     if interpret is None:
         interpret = default_interpret()
 
-    in_specs = [pl.BlockSpec((1, 1, r, hd), lambda b, g, p: (b, g, 0, 0))]
+    in_specs = [pl.BlockSpec((1, 1, r, hd), lambda b, g, p, *_: (b, g, 0, 0))]
     for a in payload:
         if a.ndim == 5:
             in_specs.append(pl.BlockSpec((1, 1, nb, a.shape[3], hd),
-                                         lambda b, g, p: (b, p, 0, 0, g)))
+                                         lambda b, g, p, *_: (b, p, 0, 0, g)))
         else:                                                  # scale
             in_specs.append(pl.BlockSpec((1, 1, 1, hd),
-                                         lambda b, g, p: (b, p, 0, g)))
-    in_specs += [
-        pl.BlockSpec((1, 1), lambda b, g, p: (b, p)),          # page ids
-        pl.BlockSpec((1, 1), lambda b, g, p: (b, 0)),          # n_valid
-    ]
+                                         lambda b, g, p, *_: (b, p, 0, g)))
+    out_spec = lambda rows: pl.BlockSpec(                      # noqa: E731
+        (1, 1, r, rows), lambda b, g, p, *_: (b, g, 0, 0))
 
     acc, m, l = pl.pallas_call(
         kern,
-        grid=(b, kv, pp),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, r, hd), lambda b, g, p: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, r, 1), lambda b, g, p: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, r, 1), lambda b, g, p: (b, g, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, kv, pp),
+            in_specs=in_specs,
+            out_specs=[out_spec(hd), out_spec(1), out_spec(1)],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, kv, r, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, kv, r, 1), jnp.float32),
@@ -178,7 +173,7 @@ def _call(kern, q4, payload, page_ids, n_valid, nb, w, interpret):
         ],
         interpret=interpret,
         compiler_params=_mosaic_params(interpret, grid_rank=3),
-    )(q4, *payload, page_ids, n_valid)
+    )(page_ids, n_valid, q4, *payload)
     return acc, m[..., 0], l[..., 0]
 
 
@@ -216,8 +211,8 @@ def strum_paged_attention_pallas(
     payload = [_pad_rows(k_mask), _pad_rows(k_hi), _pad_rows(k_lo), k_scale,
                _pad_rows(v_mask), _pad_rows(v_hi), _pad_rows(v_lo), v_scale]
     kern = functools.partial(_kernel, w=w, n_low=n_low, q=q, method=method)
-    return _call(kern, q4, payload, page_ids,
-                 n_valid.reshape(b, -1)[:, :1].astype(jnp.int32),
+    return _call(kern, q4, payload, page_ids.astype(jnp.int32),
+                 n_valid.reshape(b, -1)[:, 0].astype(jnp.int32),
                  nb, w, interpret)
 
 
@@ -233,6 +228,6 @@ def strum_paged_attention_pallas_maskfree(
     assert k_lo.shape[-1] == kv * hd, (k_lo.shape, kv, hd)
     payload = [_pad_rows(k_lo), k_scale, _pad_rows(v_lo), v_scale]
     kern = functools.partial(_kernel_maskfree, w=w, q=q, method=method)
-    return _call(kern, q4, payload, page_ids,
-                 n_valid.reshape(b, -1)[:, :1].astype(jnp.int32),
+    return _call(kern, q4, payload, page_ids.astype(jnp.int32),
+                 n_valid.reshape(b, -1)[:, 0].astype(jnp.int32),
                  nb, w, interpret)

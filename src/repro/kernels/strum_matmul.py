@@ -12,28 +12,35 @@ a static shape — BlockSpecs address the payload with plain block indices, no
 indirection tables (the paper's "slowest-PE balance" property, here:
 uniform DMA descriptors).
 
-Decode strategy inside the kernel (vectorized, gather-free):
-  1. unpack mask bits with shift/and on a broadcasted iota,
-  2. per-position rank among its set via ``lax.cumsum`` along the block dim,
-  3. payload → position scatter as a one-hot ⋅ payload contraction
-     (w ≤ 32, n_high ≤ 16 → tiny VPU-friendly einsum, no dynamic gather),
-  4. low codes decoded per method:  DLIQ  mantissa << (8-q)  (the INT4×INT8
-     multiplier path),  MIP2Q  ±2**k  (the barrel-shifter path — an exact
-     shift, computed as an exp2 on the shift field),
-  5. f32 (values · per-channel scale) tile → MXU dot, f32 accumulation.
+Decode strategy inside the kernel (vectorized, gather-free, int32 bit
+arithmetic throughout — Mosaic's vector shifts and iotas are 32-bit):
+  1. the ``w // 8`` mask bytes of each block form one int32 word; a
+     position's bit and its rank among the high positions (a popcount of
+     the word's lower bits) come from shifts and ands against a position
+     iota — no prefix scan along the block,
+  2. each position gets a slot in the concatenated payload ``hi ++ lo``,
+     and the payload rows land by one select per row (w <= 32) — no dynamic
+     gather and no (w, count) one-hot intermediate,
+  3. low codes decoded per method:  DLIQ  mantissa << (8-q)  (the INT4xINT8
+     multiplier path),  MIP2Q  +-(1 << k)  (the barrel-shifter path),
+  4. f32 (values * per-channel scale) tile -> MXU dot, f32 accumulation.
 
-Validated in ``interpret=True`` mode on CPU against ``ref.strum_matmul_ref``.
+Every kernel compiles through Mosaic for TPU v5e at the widths the models
+serve with (``tests/test_tpu_compile.py``, against a described chip) and
+matches ``ref.strum_matmul_ref`` in interpret mode on CPU
+(``tests/test_kernels.py``); ``chip_smoke.py`` serves OLMo-1B through them
+on a real chip.
 
 Besides the general ``strum_matmul_pallas`` (the one-hot scatter decode that
 handles every method × n_low), two *specialized* lowerings exist for the
 schedule extremes the autotuner actually emits — they stream fewer operands
-and skip the rank/one-hot machinery entirely:
+and skip the mask/rank machinery entirely:
 
 ``strum_matmul_pallas_maskfree``  p = 1.0 (n_low == w): every value is low
                                   precision, so the mask is all-zeros and the
                                   lo payload is already in position order —
                                   decode is unpack-fields → method decode →
-                                  reshape.  No mask or hi stream at all.
+                                  place by position.  No mask or hi stream.
 ``strum_matmul_pallas_dense``     n_low == 0: every value is INT8 and the hi
                                   payload is the block in position order —
                                   decode is a reshape + scale.  No mask or lo
@@ -45,8 +52,8 @@ one grid dimension per expert/scan group, so MoE expert stacks execute
 compressed end-to-end instead of falling back to dequantize + XLA einsum.
 Every group streams its own packed payload tile (same uniform DMA
 descriptors: StruM's fixed ``n_low`` keeps block shapes static across
-experts), and the decode helpers (`_decode_tile`, `_unpack_fields`,
-`_decode_low`) are shared with the 2-D kernels verbatim.
+experts), and the decode helpers (`_decode_tile`, `_decode_tile_maskfree`)
+are shared with the 2-D kernels verbatim.
 
 Selection between these lives in :mod:`repro.engine.registry` — the kernels
 themselves stay selection-free.
@@ -59,6 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _scoped(name):
@@ -86,73 +94,114 @@ __all__ = [
 ]
 
 
-def _unpack_mask(mask_u8: jnp.ndarray, w: int) -> jnp.ndarray:
-    """(bnb, w//8, bn) uint8 -> (bnb, w, bn) bool (LSB-first), iota-based."""
-    bnb, mb, bn = mask_u8.shape
-    bits_shape = (bnb, mb, 8, bn)
-    bit_idx = lax.broadcasted_iota(jnp.uint8, bits_shape, 2)
-    bits = (mask_u8[:, :, None, :] >> bit_idx) & jnp.uint8(1)
-    return bits.reshape(bnb, mb * 8, bn).astype(jnp.bool_)[:, :w, :]
+# The decode helpers below keep every intermediate 3-D with the block axis
+# second-minor: payload rows are sliced as (bnb, 1, bn) and broadcast
+# against (bnb, w, bn) position tiles, so Mosaic never relayouts a row into
+# a different tiling.  All bit arithmetic is int32 (Mosaic's vector shifts
+# and iotas are 32-bit; uint8 payloads are widened first).
+
+def _popcount(x: jnp.ndarray) -> jnp.ndarray:
+    """Set bits of a non-negative int32 (SWAR; shifts, ands and adds only)."""
+    x = x - ((x >> 1) & 0x55555555)
+    x = (x & 0x33333333) + ((x >> 2) & 0x33333333)
+    x = (x + (x >> 4)) & 0x0F0F0F0F
+    x = x + (x >> 8)
+    x = x + (x >> 16)
+    return x & 0x3F
 
 
-def _unpack_fields(lo_u8: jnp.ndarray, n_low: int, q: int) -> jnp.ndarray:
-    """(bnb, ceil(n_low*q/8), bn) uint8 -> (bnb, n_low, bn) int32 codes."""
-    bnb, lb, bn = lo_u8.shape
-    bit_idx = lax.broadcasted_iota(jnp.uint8, (bnb, lb, 8, bn), 2)
-    bits = ((lo_u8[:, :, None, :] >> bit_idx) & jnp.uint8(1)).reshape(bnb, lb * 8, bn)
-    bits = bits[:, : n_low * q, :].reshape(bnb, n_low, q, bn).astype(jnp.int32)
-    weights = lax.broadcasted_iota(jnp.int32, (bnb, n_low, q, bn), 2)
-    return jnp.sum(bits << weights, axis=2)
+def _mask_rank(mask_u8: jnp.ndarray, w: int):
+    """(bnb, w//8, bn) uint8 mask header -> ``(high, rank)``, both (bnb, w, bn).
 
-
-def _scatter_onehot(payload: jnp.ndarray, member: jnp.ndarray) -> jnp.ndarray:
-    """Place payload[r] at the r-th True position of ``member`` along axis 1.
-
-    payload: (bnb, count, bn) f32/int32;  member: (bnb, w, bn) bool.
-    Returns (bnb, w, bn) with zeros off-set.  One-hot contraction — no
-    dynamic gather, Mosaic-friendly.
+    ``high`` is the LSB-first bit of each position; ``rank`` counts the high
+    positions before it in its block.  The header's ``w // 8 <= 4`` bytes
+    form one int32 word per (block, column), so the in-block rank is a
+    popcount of the word's lower bits — no prefix scan along the block.
     """
-    bnb, count, bn = payload.shape
-    w = member.shape[1]
-    if count == 0:
-        return jnp.zeros((bnb, w, bn), payload.dtype)
-    m32 = member.astype(jnp.int32)
-    rank = lax.cumsum(m32, axis=1) - m32                    # (bnb, w, bn)
-    r_idx = lax.broadcasted_iota(jnp.int32, (bnb, w, count, bn), 2)
-    onehot = (rank[:, :, None, :] == r_idx) & member[:, :, None, :]
-    return jnp.sum(
-        onehot.astype(payload.dtype) * payload[:, None, :, :], axis=2
-    )
+    bnb, mb, bn = mask_u8.shape
+    m = mask_u8.astype(jnp.int32)
+    word = m[:, 0:1, :]
+    for b in range(1, mb):
+        word = word | (m[:, b:b + 1, :] << (8 * b))           # (bnb, 1, bn)
+    pos = lax.broadcasted_iota(jnp.int32, (bnb, w, bn), 1)
+    high = ((word >> pos) & 1) == 1
+    rank = _popcount(word & ((1 << pos) - 1))     # bit 31 never survives
+    return high, rank
+
+
+def _unpack_fields(lo_u8: jnp.ndarray, n_low: int, q: int) -> list:
+    """(bnb, ceil(n_low*q/8), bn) uint8 -> ``n_low`` (bnb, 1, bn) int32 codes.
+
+    Field ``j`` holds bits ``[j*q, (j+1)*q)`` of the LSB-first byte stream,
+    so it reads one byte, or two where it straddles a byte boundary.
+    """
+    lo = lo_u8.astype(jnp.int32)
+    out = []
+    for j in range(n_low):
+        byte, shift = divmod(j * q, 8)
+        v = lo[:, byte:byte + 1, :] >> shift
+        if shift + q > 8:
+            v = v | (lo[:, byte + 1:byte + 2, :] << (8 - shift))
+        out.append(v & ((1 << q) - 1))
+    return out
 
 
 def _decode_low(codes: jnp.ndarray, method: str, q: int) -> jnp.ndarray:
     """q-bit payload fields -> f32 values on the int8 grid."""
     if method == "sparsity":
-        return jnp.zeros_like(codes, jnp.float32)
+        return jnp.zeros(codes.shape, jnp.float32)
     if method == "dliq":
         sign_bit = 1 << (q - 1)
         mant = (codes ^ sign_bit) - sign_bit        # sign-extend q bits
         return (mant << (8 - q)).astype(jnp.float32)
     if method == "mip2q":
-        sgn = 1.0 - 2.0 * (codes >> (q - 1)).astype(jnp.float32)
-        k = (codes & ((1 << (q - 1)) - 1)).astype(jnp.float32)
-        return sgn * jnp.exp2(k)                    # the barrel shift ±2**k
+        sgn = 1 - 2 * (codes >> (q - 1))
+        k = codes & ((1 << (q - 1)) - 1)
+        return (sgn * (1 << k)).astype(jnp.float32)  # the barrel shift ±2**k
     raise ValueError(method)
 
 
+def _place(rows: list, slot: jnp.ndarray) -> jnp.ndarray:
+    """out[b, i, n] = rows[slot[b, i, n]][b, 0, n]; out-of-range slots -> 0.
+
+    A select per payload row (w <= 32): the one-hot scatter without a
+    dynamic gather and without a (w, count) one-hot intermediate.
+    """
+    out = jnp.zeros(slot.shape, jnp.float32)
+    for s, row in enumerate(rows):
+        out = jnp.where(slot == s, row, out)
+    return out
+
+
 def _decode_tile(mask_u8, hi_i8, lo_u8, scale_f32, *, w, n_low, q, method):
-    """Decompress one (bk, bn) weight tile in VMEM; returns f32."""
-    high = _unpack_mask(mask_u8, w)                          # (bnb, w, bn)
-    hi_vals = _scatter_onehot(hi_i8.astype(jnp.float32), high)
+    """Decompress one (bk, bn) weight tile in VMEM; returns f32.
+
+    Every position gets a slot in the concatenated payload ``hi ++ lo``:
+    its rank among the high positions, or ``n_high`` plus its rank among
+    the low ones.  Sparsity's low positions get no slot and decode to 0.
+    """
+    n_high = w - n_low
+    high, rank = _mask_rank(mask_u8, w)                      # (bnb, w, bn)
+    hi = hi_i8.astype(jnp.float32)
+    rows = [hi[:, i:i + 1, :] for i in range(n_high)]
     if method == "sparsity" or n_low == 0:
-        vals = hi_vals
+        slot = jnp.where(high, rank, w)
     else:
-        codes = _unpack_fields(lo_u8, n_low, q)
-        lo_dec = _decode_low(codes, method, q)               # (bnb, n_low, bn)
-        lo_vals = _scatter_onehot(lo_dec, ~high)
-        vals = jnp.where(high, hi_vals, lo_vals)
+        pos = lax.broadcasted_iota(jnp.int32, high.shape, 1)
+        slot = jnp.where(high, rank, n_high + pos - rank)
+        rows += [_decode_low(c, method, q)
+                 for c in _unpack_fields(lo_u8, n_low, q)]
+    vals = _place(rows, slot)
     bnb, _, bn = vals.shape
     return vals.reshape(bnb * w, bn) * scale_f32             # (bk, bn) f32
+
+
+def _decode_tile_maskfree(lo_u8, scale_f32, *, w, q, method):
+    """p = 1.0 decode: the lo fields are the whole block in position order."""
+    rows = [_decode_low(c, method, q) for c in _unpack_fields(lo_u8, w, q)]
+    bnb, _, bn = lo_u8.shape
+    vals = _place(rows, lax.broadcasted_iota(jnp.int32, (bnb, w, bn), 1))
+    return vals.reshape(bnb * w, bn) * scale_f32
 
 
 def _kernel(x_ref, mask_ref, hi_ref, lo_ref, scale_ref, o_ref, *,
@@ -215,8 +264,8 @@ def _mosaic_params(interpret: bool, grid_rank: int = 3):
     if interpret:
         return None
     # all axes are parallel except the innermost reduction (k) axis
-    return dict(mosaic=dict(
-        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",)))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",))
 
 
 def _kernel_maskfree(x_ref, lo_ref, scale_ref, o_ref, *, w, q, method):
@@ -227,10 +276,8 @@ def _kernel_maskfree(x_ref, lo_ref, scale_ref, o_ref, *, w, q, method):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = _unpack_fields(lo_ref[...], w, q)                # (bnb, w, bn)
-    vals = _decode_low(codes, method, q)
-    bnb, _, bn = vals.shape
-    wv = vals.reshape(bnb * w, bn) * scale_ref[...]
+    wv = _decode_tile_maskfree(lo_ref[...], scale_ref[...], w=w, q=q,
+                               method=method)
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
 
@@ -329,17 +376,16 @@ def strum_matmul_pallas_dense(x, hi, scale, *, w: int,
 #                                    leading positions — position-scrambled
 #                                    and lossier, but mask- and lo-free.
 
-def _kernel_histream(x_ref, mask_ref, hi_ref, scale_ref, o_ref, *, w):
+def _kernel_histream(x_ref, mask_ref, hi_ref, scale_ref, o_ref, *, w, n_low):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    high = _unpack_mask(mask_ref[...], w)                    # (bnb, w, bn)
-    vals = _scatter_onehot(hi_ref[...].astype(jnp.float32), high)
-    bnb, _, bn = vals.shape
-    wv = vals.reshape(bnb * w, bn) * scale_ref[...]
+    # the sparsity decode: high values placed, every low position zero
+    wv = _decode_tile(mask_ref[...], hi_ref[...], None, scale_ref[...],
+                      w=w, n_low=n_low, q=0, method="sparsity")
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
 
@@ -363,7 +409,7 @@ def strum_matmul_pallas_histream(x, mask, hi, scale, *, w: int, n_low: int,
     assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
     bnb = block_k // w
     grid = (m // block_m, n // block_n, k_dim // block_k)
-    kern = functools.partial(_kernel_histream, w=w)
+    kern = functools.partial(_kernel_histream, w=w, n_low=n_low)
     n_high = w - n_low
     mb = w // 8
     return pl.pallas_call(
@@ -510,10 +556,8 @@ def _kernel_grouped_maskfree(x_ref, lo_ref, scale_ref, o_ref, *, w, q, method):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = _unpack_fields(lo_ref[0], w, q)                  # (bnb, w, bn)
-    vals = _decode_low(codes, method, q)
-    bnb, _, bn = vals.shape
-    wv = vals.reshape(bnb * w, bn) * scale_ref[0]
+    wv = _decode_tile_maskfree(lo_ref[0], scale_ref[0], w=w, q=q,
+                               method=method)
     x = x_ref[0].astype(jnp.float32)
     o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)[None]
 

@@ -8,15 +8,24 @@ init; tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh"]
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate
+    through the program and ``with_sharding_constraint`` steers them (the
+    sharding-in-types ``Explicit`` default would make each op's output
+    sharding part of its type instead)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 dual pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pp: int = 0):
@@ -24,5 +33,5 @@ def make_host_mesh(data: int = 1, model: int = 1, pp: int = 0):
     n = len(jax.devices())
     assert data * model * max(pp, 1) <= n, (data, model, pp, n)
     if pp:
-        return jax.make_mesh((pp, data, model), ("pp", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pp, data, model), ("pp", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -12,6 +12,8 @@ shapes and shardings.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import zlib
 from typing import Optional
 
 import jax
@@ -45,26 +47,38 @@ def _fan_in(shape) -> int:
     return int(np.prod(shape[:-1])) if len(shape) > 1 else max(shape[0], 1)
 
 
-def init_params(defs, seed: int = 0, dtype_override: Optional[str] = None):
+def init_params(defs, seed: int = 0, dtype_override: Optional[str] = None,
+                shardings=None):
     """Materialize a ParamDef tree.  Deterministic: each leaf's key is
-    fold_in(seed, hash(path)) — stable across processes/hosts."""
+    fold_in(seed, crc32(path)) — stable across processes/hosts (unlike
+    ``hash(str)``, which Python salts per process).
+
+    ``shardings`` (e.g. :func:`param_shardings`) draws every leaf directly
+    in its sharded layout, so a model larger than one device never lands
+    whole on the first; the values do not depend on it.
+    """
     flat = jax.tree_util.tree_flatten_with_path(defs, is_leaf=_is_def)
     leaves, treedef = flat
+    shard_leaves = ([None] * len(leaves) if shardings is None else
+                    treedef.flatten_up_to(shardings))
     out = []
     root = jax.random.PRNGKey(seed)
-    for path, d in leaves:
+    for (path, d), sharding in zip(leaves, shard_leaves):
         name = "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        dt = _resolve_dtype(d, dtype_override)
-        if d.init == "zeros":
-            arr = jnp.zeros(d.shape, dt)
-        elif d.init == "ones":
-            arr = jnp.ones(d.shape, dt)
-        else:
-            key = jax.random.fold_in(root, hash(name) & 0x7FFFFFFF)
-            std = d.scale / np.sqrt(_fan_in(d.shape)) if d.init == "normal" else d.scale
-            arr = (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dt)
-        out.append(arr)
+        key = jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+        make = functools.partial(_draw, d, _resolve_dtype(d, dtype_override))
+        out.append(make(key) if sharding is None else
+                   jax.jit(make, out_shardings=sharding)(key))
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _draw(d: ParamDef, dt, key):
+    if d.init == "zeros":
+        return jnp.zeros(d.shape, dt)
+    if d.init == "ones":
+        return jnp.ones(d.shape, dt)
+    std = d.scale / np.sqrt(_fan_in(d.shape)) if d.init == "normal" else d.scale
+    return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dt)
 
 
 def _resolve_dtype(d: ParamDef, override: Optional[str]):

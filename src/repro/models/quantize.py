@@ -20,6 +20,7 @@ StruM is a storage/bandwidth transform, not an architecture change.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -42,6 +43,18 @@ except ValueError:
     pass  # already registered (module reload)
 
 
+@functools.partial(jax.jit, static_argnames=("scfg",))
+def _pack_2d(w: jnp.ndarray, scfg: StruMConfig) -> dict:
+    """One (K, N) kernel -> its payload arrays, as one compiled program
+    (a whole-model plan packs each weight shape once, not op by op)."""
+    codes, scale = int8_symmetric(w, axis=0)
+    blocks = blocking.to_blocks(codes, scfg.w)
+    qb = quantize_blocks(blocks, scfg.method, scfg.n_low, q=scfg.q, L=scfg.L)
+    p = packing.pack(qb, method=scfg.method, scale=scale, k_dim=w.shape[0],
+                     n_low=scfg.n_low, q=scfg.q, L=scfg.L)
+    return {"mask": p.mask, "hi": p.hi, "lo": p.lo, "scale": p.scale}
+
+
 def _pack_leaf(wt: jnp.ndarray, scfg: StruMConfig) -> dict:
     """(..., K, N) kernel -> compressed arrays with lead dims preserved.
 
@@ -51,16 +64,7 @@ def _pack_leaf(wt: jnp.ndarray, scfg: StruMConfig) -> dict:
     lead = wt.shape[:-2]
     k, n = wt.shape[-2:]
     w2 = wt.reshape((-1, k, n))
-
-    def pack_one(w):
-        codes, scale = int8_symmetric(w, axis=0)
-        blocks = blocking.to_blocks(codes, scfg.w)
-        qb = quantize_blocks(blocks, scfg.method, scfg.n_low, q=scfg.q, L=scfg.L)
-        p = packing.pack(qb, method=scfg.method, scale=scale, k_dim=k,
-                         n_low=scfg.n_low, q=scfg.q, L=scfg.L)
-        return {"mask": p.mask, "hi": p.hi, "lo": p.lo, "scale": p.scale}
-
-    packed = [pack_one(w2[i]) for i in range(w2.shape[0])]
+    packed = [_pack_2d(w2[i], scfg) for i in range(w2.shape[0])]
     return {key: jnp.stack([p[key] for p in packed]).reshape(
         lead + packed[0][key].shape) for key in packed[0]}
 

@@ -42,14 +42,9 @@ def fsdp_axes(mesh) -> tuple:
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map: ``jax.shard_map`` (new API, check_vma)
-    when present, else ``jax.experimental.shard_map`` (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 # logical axis -> mesh axis (or tuple of mesh axes); None = replicated
 TRAIN_RULES: dict = {

@@ -99,3 +99,30 @@ def test_param_counts_match_published():
         assert abs(got - want) / want < tol, (arch, got)
     active = get_config("qwen3_moe_235b_a22b").active_param_count()
     assert abs(active - 22e9) / 22e9 < 0.05
+
+
+def test_init_params_identical_across_processes():
+    """Leaf keys come from a stable digest of the path, so two interpreters
+    (each with its own ``str`` hash salt) draw the same weights."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import jax, numpy as np\n"
+        "from repro.configs import get_smoke_config\n"
+        "from repro.models import model_defs\n"
+        "from repro.models.params import init_params\n"
+        "p = init_params(model_defs(get_smoke_config('olmo_1b')), seed=3)\n"
+        "print([float(np.abs(np.asarray(x, np.float64)).sum())\n"
+        "       for x in jax.tree.leaves(p)])\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
+    sums = []
+    for salt in ("1", "2"):
+        env["PYTHONHASHSEED"] = salt
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300,
+                             check=True)
+        sums.append(out.stdout.strip().splitlines()[-1])
+    assert sums[0] == sums[1]
