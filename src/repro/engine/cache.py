@@ -49,7 +49,7 @@ from repro.engine.registry import (LeafInfo, register_kernel, resolve_backend,
 __all__ = ["CacheSpec", "build_cache_spec", "select_cache_variant",
            "select_attn_variant", "encode_page", "decode_pages",
            "gather_decode_pages", "attn_sealed_partial",
-           "page_payload_bytes"]
+           "page_payload_bytes", "pages_gathered"]
 
 CACHE_PAYLOAD_KEYS = ("mask", "hi", "lo", "scale")
 
@@ -311,8 +311,6 @@ def attn_sealed_partial(pool: dict, qf: jnp.ndarray, page_table: jnp.ndarray,
         _, interpret = resolve_backend(backend)
         variant = select_attn_variant(spec.cfg, page_size=spec.page_size,
                                       feat=1, backend=backend)
-    if telemetry.enabled():
-        telemetry.inc(f"attn/variant/{variant.name}")
     span = variant.name.replace("cache:attn_", "attn:")
     with telemetry.span(span, cat="attn"), jax.named_scope(span):
         return variant.fn(pool, qf, page_table, n_valid, cfg=spec.cfg,
@@ -355,6 +353,14 @@ def _gather_packed(pool: dict, page_table: jnp.ndarray, keys) -> dict:
     sealed pools on the fused path, and it moves packed bytes only."""
     ids = jnp.clip(page_table, 0, None)
     return {k: jnp.take(pool[k], ids, axis=0) for k in keys}
+
+
+def pages_gathered(page_table_shape) -> int:
+    """Pages one sealed-page attention call reads per attention position
+    for a page table of ``page_table_shape`` (n_slots, pages_per_seq):
+    ``_gather_packed`` (and the unfused path's page decode) takes every
+    entry of the table, sealed or not."""
+    return math.prod(page_table_shape)
 
 
 def _note_fused_bytes(gk: dict, gv: dict) -> None:

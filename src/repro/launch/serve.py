@@ -55,10 +55,8 @@ def serve(cfg, params, prompt: jnp.ndarray, gen: int, strum_kw: dict,
 
 
 def _serve(cfg, params, prompt: jnp.ndarray, gen: int, mesh, rules):
-    prefill_fn = jax.jit(
-        lambda p, b: make_prefill_step(cfg, mesh, rules)(p, b))
-    decode_fn = jax.jit(
-        lambda p, t, c, n: make_decode_step(cfg, mesh, rules)(p, t, c, n))
+    prefill_fn = jax.jit(make_prefill_step(cfg, mesh, rules))
+    decode_fn = jax.jit(make_decode_step(cfg, mesh, rules))
 
     t0 = time.time()
     lg, caches = prefill_fn(params, {"tokens": prompt})

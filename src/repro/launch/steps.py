@@ -17,7 +17,19 @@ from repro.runtime import compression as gcomp
 
 __all__ = ["make_train_step", "make_prefill_step", "make_decode_step",
            "make_paged_decode_step", "make_chunked_prefill_step",
-           "make_verify_step", "build_serving_plan"]
+           "make_verify_step", "build_serving_plan", "LANE_PROGRAMS"]
+
+# The HLO module each serving lane compiles to: ``jit_`` and the name of the
+# step function its builder returns.  The device trace's ``XLA Modules``
+# line shows each lane's runs under this name (the KV-page sealer of
+# ``serving.pages.make_sealer`` runs as ``jit_seal``).
+LANE_PROGRAMS = {
+    "decode": "jit_decode_step",                # make_paged_decode_step
+    "prefill_chunk": "jit_prefill_chunk_step",  # make_chunked_prefill_step
+    "prefill": "jit_prefill_step",              # make_prefill_step
+    "verify": "jit_verify_step",                # make_verify_step
+    "dense_decode": "jit_dense_decode_step",    # make_decode_step
+}
 
 
 def build_serving_plan(params, *, schedule=None, cfg=None, policy=None,
@@ -72,18 +84,18 @@ def make_train_step(cfg, opt_cfg: adamw.AdamWConfig, mesh=None,
 def make_prefill_step(cfg, mesh=None, rules: Optional[Rules] = None):
     rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
 
-    def step(params, batch):
+    def prefill_step(params, batch):
         return tfm.prefill(params, batch, cfg, mesh=mesh, rules=rules)
-    return step
+    return prefill_step
 
 
 def make_decode_step(cfg, mesh=None, rules: Optional[Rules] = None):
     rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
 
-    def step(params, token, caches, cache_len):
+    def dense_decode_step(params, token, caches, cache_len):
         return tfm.decode_step(params, token, caches, cache_len, cfg,
                                mesh=mesh, rules=rules)
-    return step
+    return dense_decode_step
 
 
 def make_paged_decode_step(cfg, spec, mesh=None,
@@ -94,12 +106,12 @@ def make_paged_decode_step(cfg, spec, mesh=None,
     rides the closure as static codec metadata."""
     rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
 
-    def step(params, token, pools, hot, cache_len, page_table, active):
+    def decode_step(params, token, pools, hot, cache_len, page_table, active):
         return tfm.decode_step_paged(params, token, pools, hot, cache_len,
                                      page_table, active, spec, cfg,
                                      mesh=mesh, rules=rules,
                                      cache_backend=cache_backend)
-    return step
+    return decode_step
 
 
 def make_chunked_prefill_step(cfg, spec, mesh=None,
@@ -110,12 +122,13 @@ def make_chunked_prefill_step(cfg, spec, mesh=None,
     compile-per-prompt-length path."""
     rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
 
-    def step(params, tokens, pools, hot, page_table, slot, start, valid_len):
+    def prefill_chunk_step(params, tokens, pools, hot, page_table, slot,
+                           start, valid_len):
         return tfm.prefill_chunk_step(params, tokens, pools, hot, page_table,
                                       slot, start, valid_len, spec, cfg,
                                       mesh=mesh, rules=rules,
                                       cache_backend=cache_backend)
-    return step
+    return prefill_chunk_step
 
 
 def make_verify_step(cfg, spec, mesh=None, rules: Optional[Rules] = None,
@@ -126,9 +139,9 @@ def make_verify_step(cfg, spec, mesh=None, rules: Optional[Rules] = None,
     itself (its rollback)."""
     rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
 
-    def step(params, tokens, pools, hot, page_table, slot, start):
+    def verify_step(params, tokens, pools, hot, page_table, slot, start):
         return tfm.verify_chunk_step(params, tokens, pools, hot, page_table,
                                      slot, start, spec, cfg, mesh=mesh,
                                      rules=rules,
                                      cache_backend=cache_backend)
-    return step
+    return verify_step

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
+from repro.engine.cache import pages_gathered
 from repro.launch.steps import (make_chunked_prefill_step,
                                 make_paged_decode_step, make_prefill_step,
                                 make_verify_step)
@@ -297,7 +298,8 @@ class BatchScheduler:
         sl = self.slots[slot]
         pid = sl.pages[page_idx]
         pid_dev = jnp.int32(pid)
-        with telemetry.span("sched:seal", slot=slot, page=pid):
+        with telemetry.span("sched:seal", slot=slot, page=pid, uid=sl.req.uid,
+                            calls=len(self._attn_pos)):
             for pos in self._attn_pos:
                 k_page, v_page = kv_pages[pos]
                 self.pools[pos] = self._seal(self.pools[pos], k_page, v_page,
@@ -372,8 +374,9 @@ class BatchScheduler:
         # tick the decode batch also runs in; that asymmetry IS the
         # head-of-line blocking serving_bench measures.)
         self._stall += -(-plen // self.prefill_chunk)
-        tok = jnp.argmax(lg[0, -1, :self.cfg.vocab_size])
-        self._finish_prefill(slot, int(tok))
+        with telemetry.span("sched:sync"):
+            tok = int(jnp.argmax(lg[0, -1, :self.cfg.vocab_size]))
+        self._finish_prefill(slot, tok)
 
     def _prefill_slots(self) -> list:
         return [s for s in range(self.n_slots)
@@ -391,14 +394,17 @@ class BatchScheduler:
         if start == 0:
             telemetry.request_event(sl.req.uid, "prefill", mode="chunked",
                                     prompt_len=plen)
-        toks = np.zeros((1, c), np.int32)
-        toks[0, :valid] = prompt[start:start + valid]
         with telemetry.span("sched:prefill_chunk", slot=slot, start=start,
-                            valid=valid):
+                            valid=valid, uid=sl.req.uid):
+            with telemetry.span("sched:inputs"):
+                toks = np.zeros((1, c), np.int32)
+                toks[0, :valid] = prompt[start:start + valid]
+                toks_d, table_d = jnp.asarray(toks), jnp.asarray(self._table)
+                slot_d, start_d = jnp.int32(slot), jnp.int32(start)
+                valid_d = jnp.int32(valid)
             lg, self.hot, chunk_kv = self._chunk_prefill(
-                self.params, jnp.asarray(toks), self.pools, self.hot,
-                jnp.asarray(self._table), jnp.int32(slot), jnp.int32(start),
-                jnp.int32(valid))
+                self.params, toks_d, self.pools, self.hot, table_d, slot_d,
+                start_d, valid_d)
         new_len = start + valid
         ps = self.page_size
         for j in range(sl.n_sealed, new_len // ps):
@@ -410,8 +416,9 @@ class BatchScheduler:
         sl.pf_start = start + valid
         sl.len = new_len
         if sl.pf_start >= plen:
-            tok = jnp.argmax(lg[0, valid - 1, :self.cfg.vocab_size])
-            self._finish_prefill(slot, int(tok))
+            with telemetry.span("sched:sync"):
+                tok = int(jnp.argmax(lg[0, valid - 1, :self.cfg.vocab_size]))
+            self._finish_prefill(slot, tok)
 
     # ------------------------------------------------------------- decode --
     def _retire(self, slot: int) -> None:
@@ -438,31 +445,42 @@ class BatchScheduler:
                 cache_len[s] = self.slots[s].len
         mask = np.zeros((self.n_slots,), bool)
         mask[active] = True
-        with telemetry.span("sched:decode", n_active=len(active)):
-            lg, self.hot = self._decode(
-                self.params, jnp.asarray(self._tokens, jnp.int32)[:, None],
-                self.pools, self.hot, jnp.asarray(cache_len),
-                jnp.asarray(self._table), jnp.asarray(mask))
+        args = {"n_active": len(active)}
+        if telemetry.enabled():
+            # sealed pages the attention reads against the pages it gathers
+            args.update(pages_gathered=pages_gathered(self._table.shape),
+                        pages_valid=sum(self.slots[s].n_sealed
+                                        for s in active))
+        with telemetry.span("sched:decode", **args):
+            with telemetry.span("sched:inputs"):
+                tok_d = jnp.asarray(self._tokens, jnp.int32)[:, None]
+                len_d = jnp.asarray(cache_len)
+                table_d = jnp.asarray(self._table)
+                mask_d = jnp.asarray(mask)
+            lg, self.hot = self._decode(self.params, tok_d, self.pools,
+                                        self.hot, len_d, table_d, mask_d)
             # np.asarray blocks on the device step, so the token events
             # below carry post-compute wall-clock timestamps
-            nxt = np.asarray(
-                jnp.argmax(lg[:, -1, :self.cfg.vocab_size], axis=-1))
-        for s in active:
-            sl = self.slots[s]
-            req = sl.req
-            tok = int(nxt[s])
-            req.output.append(tok)
-            telemetry.request_event(req.uid, "token", slot=s)
-            sl.len += 1
-            if sl.len % self.page_size == 0 \
-                    and sl.len // self.page_size <= len(sl.pages):
-                self._seal_tails(s)
-            if ((req.eos_id is not None and tok == req.eos_id)
-                    or len(req.output) >= req.max_new_tokens
-                    or sl.len >= self.max_len - 2):
-                self._retire(s)
-                continue
-            self._tokens[s] = req._feed(len(req.output) - 1, tok)
+            with telemetry.span("sched:sync"):
+                nxt = np.asarray(
+                    jnp.argmax(lg[:, -1, :self.cfg.vocab_size], axis=-1))
+        with telemetry.span("sched:emit"):
+            for s in active:
+                sl = self.slots[s]
+                req = sl.req
+                tok = int(nxt[s])
+                req.output.append(tok)
+                telemetry.request_event(req.uid, "token", slot=s)
+                sl.len += 1
+                if sl.len % self.page_size == 0 \
+                        and sl.len // self.page_size <= len(sl.pages):
+                    self._seal_tails(s)
+                if ((req.eos_id is not None and tok == req.eos_id)
+                        or len(req.output) >= req.max_new_tokens
+                        or sl.len >= self.max_len - 2):
+                    self._retire(s)
+                    continue
+                self._tokens[s] = req._feed(len(req.output) - 1, tok)
 
     # -------------------------------------------------------- speculative --
     @staticmethod
@@ -539,8 +557,9 @@ class BatchScheduler:
                         jnp.asarray(cur, jnp.int32)[:, None], self.pools,
                         self.hot, jnp.asarray(cl), jnp.asarray(self._table),
                         jnp.asarray(mask))
-                    nxt = np.asarray(
-                        jnp.argmax(lg[:, -1, :self.cfg.vocab_size], axis=-1))
+                    with telemetry.span("sched:sync"):
+                        nxt = np.asarray(jnp.argmax(
+                            lg[:, -1, :self.cfg.vocab_size], axis=-1))
                     for s in active:
                         if j < k_eff[s]:
                             drafts[s].append(int(nxt[s]))
@@ -558,8 +577,9 @@ class BatchScheduler:
                 lg, chunk_kv = self._verify(
                     self.params, jnp.asarray(toks), self.pools, self.hot,
                     jnp.asarray(self._table), jnp.int32(s), jnp.int32(start))
-                g = np.asarray(
-                    jnp.argmax(lg[0, :, :self.cfg.vocab_size], axis=-1))
+                with telemetry.span("sched:sync"):
+                    g = np.asarray(
+                        jnp.argmax(lg[0, :, :self.cfg.vocab_size], axis=-1))
             n_acc = 0
             retired = False
             for j in range(k_eff[s] + 1):
@@ -594,9 +614,9 @@ class BatchScheduler:
         """One scheduler tick: admit, advance one prefill chunk, decode all
         decoding slots.  Returns the number of requests that progressed."""
         with telemetry.span("sched:step", tick=self._steps):
-            self._admit()
+            with telemetry.span("sched:admit"):
+                self._admit()
             progressed = 0
-            prefill_busy = 0
             if self.prefill_mode == "chunked":
                 pf = self._prefill_slots()
                 if pf:
@@ -604,21 +624,17 @@ class BatchScheduler:
                     slot = min(pf, key=lambda s: (self.slots[s].pf_start, s))
                     self._advance_prefill(slot)
                     progressed += 1
-                    prefill_busy = 1
             if telemetry.enabled():
                 telemetry.inc("sched/ticks")
                 telemetry.gauge("sched/queue_depth", len(self.queue))
-                telemetry.gauge("sched/lane/prefill_busy", prefill_busy)
             if self._stall > 0:
                 # serial mode: the monolithic prefill still occupies the
                 # device
                 self._stall -= 1
                 self._steps += 1
                 telemetry.inc("sched/stall_ticks")
-                telemetry.gauge("sched/lane/decode_active", 0)
                 return progressed + len(self._decode_slots())
             active = self._decode_slots()
-            telemetry.gauge("sched/lane/decode_active", len(active))
             if active:
                 if self.speculative:
                     self._run_speculative(active)

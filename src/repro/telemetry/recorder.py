@@ -14,6 +14,13 @@ allocation, no clock read, no lock.  Instrumented code therefore never
 needs its own ``if telemetry.enabled()`` guard (though hot paths that
 *compute* arguments may still want one).
 
+Spans also land in the JAX profiler's own trace: where ``jax`` is already
+imported, an enabled span enters a ``jax.profiler.TraceAnnotation`` of its
+name (a ``StepTraceAnnotation`` numbered by its ``tick`` for
+``sched:step``), so a ``jax.profiler`` trace shows the host phases on the
+device trace's clock.  This module never imports jax itself: the trace
+validator CLI stays jax-free.
+
 Thread safety: each recorder serializes its mutations behind one lock.
 Timestamps are ``time.perf_counter()`` microseconds relative to the
 recorder's creation — the native unit of the Chrome Trace Event Format
@@ -24,6 +31,7 @@ from __future__ import annotations
 import atexit
 import contextlib
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -200,21 +208,44 @@ class Recorder:
         return path
 
 
-class _Span:
-    """Context manager timing one wall-clock span into >=1 recorders."""
+# Spans that mark a step of the profiler's step analysis, and the argument
+# that numbers the step.
+_STEP_SPANS = {"sched:step": "tick"}
 
-    __slots__ = ("_recs", "_name", "_cat", "_args", "_t0")
+
+def _annotation(name: str, args: dict):
+    """The profiler annotation of a span, or None where jax is not loaded."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    step = _STEP_SPANS.get(name)
+    if step is not None and step in args:
+        return jax.profiler.StepTraceAnnotation(name, step_num=args[step])
+    return jax.profiler.TraceAnnotation(name)
+
+
+class _Span:
+    """Context manager timing one wall-clock span into >=1 recorders, and
+    marking it in the profiler's trace."""
+
+    __slots__ = ("_recs", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, recs, name, cat, args):
         self._recs, self._name, self._cat, self._args = recs, name, cat, args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = _annotation(self._name, self._args)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tid = threading.get_ident()
         for r in self._recs:
             r.add_span(self._name, self._t0, t1, cat=self._cat, tid=tid,
