@@ -11,7 +11,8 @@ Four trace-time passes prove engine invariants without running a kernel:
 * **Pallas lint** (:func:`lint_pallas`) — abstract-evals every
   ``pallas:*`` / ``cache:*`` variant against its tiling contracts;
 * **recompile lint** (:func:`lint_scheduler_recompiles`) — proves each
-  serving lane compiles exactly one executable across prompt lengths.
+  serving lane compiles exactly one executable across prompt lengths (the
+  sealer one per source shape).
 
 ``python -m repro.analysis`` runs them over the built-in model zoo; the
 module import is jax-free (findings/rules only) and heavy passes load

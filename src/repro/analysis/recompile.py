@@ -8,8 +8,8 @@ reintroduces the compile-per-prompt-length storm.
 
 This pass runs a deliberately shape-diverse tiny workload (mixed prompt
 lengths, more requests than slots) through a :class:`BatchScheduler` and
-then reads each lane's jit cache size — more than one trace per lane is
-``recompile/lane-retrace``.
+then reads each lane's jit cache size — more traces than a lane may hold
+(one; the sealer one per source shape) is ``recompile/lane-retrace``.
 """
 from __future__ import annotations
 
@@ -24,6 +24,11 @@ __all__ = ["lint_scheduler_recompiles", "lane_trace_counts"]
 
 #: prompt lengths chosen to straddle page and chunk boundaries
 DEFAULT_PROMPT_LENS = (3, 7, 16, 21, 33)
+
+#: executables a lane may hold; one unless listed.  The sealer cuts its page
+#: out of its source inside the program, so it compiles once per source
+#: shape: the hot tail tree and the prefill chunk's KV window.
+LANE_EXECUTABLES = {"seal": 2}
 
 
 def _cache_size(jitted) -> Optional[int]:
@@ -75,10 +80,11 @@ def lint_scheduler_recompiles(sched=None, *, cfg=None, params=None,
 
     report = Report()
     for lane, count in lane_trace_counts(sched).items():
-        if count > 1:
+        limit = LANE_EXECUTABLES.get(lane, 1)
+        if count > limit:
             report.add(
                 "error", "recompile/lane-retrace", f"{location}/{lane}",
                 f"lane compiled {count} executables across prompt lengths "
-                f"{tuple(prompt_lens)}; the fixed-shape invariant requires "
-                f"exactly one")
+                f"{tuple(prompt_lens)}; the fixed-shape invariant allows "
+                f"{limit}")
     return report

@@ -65,8 +65,9 @@ RULES: dict[str, str] = {
         "ops._pick_block / sharded._pick_m_pad violated their alignment "
         "contract for some (dim, pref, align) point",
     "recompile/lane-retrace":
-        "a scheduler lane executable compiled more than once across a "
-        "mixed-length workload — the PR-5 fixed-shape invariant regressed",
+        "a scheduler lane compiled more executables than it may (one; the "
+        "sealer one per source shape) across a mixed-length workload — the "
+        "PR-5 fixed-shape invariant regressed",
     "plan/selection-drift":
         "re-running variant selection for a plan entry under its recorded "
         "backend picks a different variant than the plan recorded",

@@ -162,19 +162,23 @@ def _unpack_fields(packed: jnp.ndarray, nl: int, q: int) -> jnp.ndarray:
 
 def _gather_compact(values: jnp.ndarray, mask: jnp.ndarray, count: int) -> jnp.ndarray:
     """Gather ``values`` where ``mask`` into a dense (nb, count, N) array,
-    preserving position order — the payload layout of Fig. 5."""
+    preserving position order — the payload layout of Fig. 5.
+
+    Payload row ``r`` is the masked sum over the block of the one value
+    whose rank among the masked positions is ``r`` (0 where there is
+    none; masked positions past ``count`` are dropped).  One reduction per
+    row (``count <= w <= 32``) instead of a scatter, which on a TPU v5e took
+    nearly all of a KV page seal's time (the seal compacts every element of
+    the page).
+    """
     nb, w, n = values.shape
     if count == 0:
         return jnp.zeros((nb, 0, n), values.dtype)
     # rank of each position among the masked ones
     rank = jnp.cumsum(mask, axis=1) - mask.astype(jnp.int32)
-    # scatter: out[rank[i]] = values[i] where mask; unmasked park in overflow
-    tgt = jnp.where(mask, rank, count)
-    out = jnp.zeros((nb, count + 1, n), values.dtype)
-    b_idx = jnp.arange(nb)[:, None, None]
-    n_idx = jnp.arange(n)[None, None, :]
-    out = out.at[b_idx, tgt, n_idx].set(values)
-    return out[:, :count, :]
+    rows = [jnp.sum(jnp.where(mask & (rank == r), values, 0), axis=1,
+                    dtype=values.dtype) for r in range(count)]
+    return jnp.stack(rows, axis=1)
 
 
 def _scatter_expand(payload: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

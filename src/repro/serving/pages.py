@@ -31,8 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import telemetry
-from repro.engine.cache import (CACHE_PAYLOAD_KEYS, CacheSpec,
-                                build_cache_spec, encode_page,
+from repro.engine.cache import (CacheSpec, build_cache_spec, encode_page,
                                 page_payload_bytes)
 
 __all__ = ["PagesExhausted", "PageAllocator", "pages_per_seq",
@@ -177,38 +176,42 @@ def init_hot(cfg, n_slots: int, page_size: int) -> dict:
 # ---------------------------------------------------------------- sealing --
 
 def make_sealer(spec: CacheSpec):
-    """One jitted executable that seals a full tail page into a pool.
+    """One jitted executable that seals one full page into a pool, in place.
 
-    ``seal(pool_pos, k_page, v_page, page_id)``: pages are
-    ``(g, page_size, kv, hd)``; ``page_id`` is a traced scalar, so sealing
-    any page of any slot reuses the same compilation (the no-recompile
-    invariant extends to cache maintenance).
+    ``seal(pool_pos, k_src, v_src, at)``: the sources are
+    ``(g, B, T, kv, hd)`` — a hot tail tree, a prefill chunk's KV window or a
+    serial prefill's cache — and ``at`` is the int32 triple
+    ``(row, start, page_id)``: the page is ``src[:, row, start:start + ps]``
+    and lands at pool page ``page_id``.  All three are traced, so sealing any
+    page of any slot reuses the compilation of its source shape.
+
+    The pool is donated: every pool leaf aliases its output, so a seal
+    writes one page instead of copying the pool.  The caller must rebind
+    its pool to the result; the arrays it passed in are deleted.
     """
     ps = spec.page_size
 
-    def _encode(page):                       # (g, ps, kv, hd) -> payloads
-        g = page.shape[0]
-        flat = page.reshape(g, ps, -1).astype(jnp.float32)
-        return jax.vmap(lambda p: encode_page(p, spec.cfg))(flat)
+    def _page(src, row, start):          # -> (g, ps, kv * hd)
+        g, _, _, kv, hd = src.shape
+        page = jax.lax.dynamic_slice(src, (0, row, start, 0, 0),
+                                     (g, 1, ps, kv, hd))
+        return page.reshape(g, ps, kv * hd)
 
-    if spec.packed:
-        def seal(pool, k_page, v_page, page_id):
-            out = dict(pool)
-            for name, page in (("k", k_page), ("v", v_page)):
-                enc = _encode(page)
-                out[name] = {k: pool[name][k].at[:, page_id].set(enc[k])
-                             for k in CACHE_PAYLOAD_KEYS}
-            return out
-    else:
-        def seal(pool, k_page, v_page, page_id):
-            out = dict(pool)
-            for name, page in (("k", k_page), ("v", v_page)):
-                g = page.shape[0]
-                flat = page.reshape(g, ps, -1)
-                out[name] = {"pages": pool[name]["pages"]
-                             .at[:, page_id].set(flat)}
-            return out
-    return jax.jit(seal)
+    def seal(pool, k_src, v_src, at):
+        row, start, page_id = at[0], at[1], at[2]
+        out = dict(pool)
+        for name, src in (("k", k_src), ("v", v_src)):
+            flat = _page(src, row, start)
+            if spec.packed:
+                new = jax.vmap(lambda p: encode_page(p, spec.cfg))(
+                    flat.astype(jnp.float32))
+            else:
+                new = {"pages": flat}
+            out[name] = {k: jax.lax.dynamic_update_index_in_dim(
+                pool[name][k], v.astype(pool[name][k].dtype), page_id, 1)
+                for k, v in new.items()}
+        return out
+    return jax.jit(seal, donate_argnums=0)
 
 
 # ------------------------------------------------------------------ stats --

@@ -289,22 +289,31 @@ class BatchScheduler:
                 self._serial_prefill(slot)
 
     # ------------------------------------------------------------ sealing --
-    def _seal_into(self, slot: int, page_idx: int, kv_pages: dict) -> None:
+    def _seal_into(self, slot: int, page_idx: int, srcs: dict, row: int,
+                   start: int) -> None:
         """Write one full page per attention position into the pools.
 
-        ``kv_pages[pos]`` is ``(k_page, v_page)`` of shape
-        (g, page_size, KV, hd).
+        ``srcs[pos]`` is ``(k_src, v_src)`` of shape (g, B, T, KV, hd); the
+        page is ``[:, row, start:start + page_size]`` of each, cut inside the
+        sealer.  The sealer consumes the pool it is given (donated), so the
+        pool is rebound to its result and nothing else may hold it.
         """
         sl = self.slots[slot]
         pid = sl.pages[page_idx]
-        pid_dev = jnp.int32(pid)
+        at = np.array([row, start, pid], np.int32)
+        in_place = True
         with telemetry.span("sched:seal", slot=slot, page=pid, uid=sl.req.uid,
                             calls=len(self._attn_pos)):
             for pos in self._attn_pos:
-                k_page, v_page = kv_pages[pos]
-                self.pools[pos] = self._seal(self.pools[pos], k_page, v_page,
-                                             pid_dev)
+                k_src, v_src = srcs[pos]
+                pool = self.pools[pos]
+                self.pools[pos] = self._seal(pool, k_src, v_src, at)
+                if telemetry.enabled():
+                    in_place &= all(leaf.is_deleted()
+                                    for leaf in jax.tree.leaves(pool))
         telemetry.inc("sched/pages_sealed")
+        if in_place:
+            telemetry.inc("sched/seals_in_place")
         self._table[slot, page_idx] = pid
         sl.n_sealed = page_idx + 1
 
@@ -312,10 +321,9 @@ class BatchScheduler:
         """Seal the (now full) tail page of ``slot``."""
         sl = self.slots[slot]
         page_idx = sl.len // self.page_size - 1
-        kv_pages = {pos: (self.hot[pos]["k_tail"][:, slot],
-                          self.hot[pos]["v_tail"][:, slot])
-                    for pos in self._attn_pos}
-        self._seal_into(slot, page_idx, kv_pages)
+        srcs = {pos: (self.hot[pos]["k_tail"], self.hot[pos]["v_tail"])
+                for pos in self._attn_pos}
+        self._seal_into(slot, page_idx, srcs, row=slot, start=0)
 
     # ------------------------------------------------------------ prefill --
     def _finish_prefill(self, slot: int, tok: int) -> None:
@@ -346,11 +354,10 @@ class BatchScheduler:
             lg, caches = self._prefill(self.params,
                                        {"tokens": sl.req.prompt[None, :]})
         n_full = plen // ps
+        srcs = {pos: (caches[pos]["k"], caches[pos]["v"])
+                for pos in self._attn_pos}
         for j in range(n_full):
-            kv_pages = {pos: (caches[pos]["k"][:, 0, j * ps:(j + 1) * ps],
-                              caches[pos]["v"][:, 0, j * ps:(j + 1) * ps])
-                        for pos in self._attn_pos}
-            self._seal_into(slot, j, kv_pages)
+            self._seal_into(slot, j, srcs, row=0, start=j * ps)
         r = plen - n_full * ps
         for pos in self.hot:
             hp = self.hot[pos]
@@ -407,12 +414,10 @@ class BatchScheduler:
                 start_d, valid_d)
         new_len = start + valid
         ps = self.page_size
+        srcs = {pos: (chunk_kv[pos]["k"], chunk_kv[pos]["v"])
+                for pos in self._attn_pos}
         for j in range(sl.n_sealed, new_len // ps):
-            rel = j * ps - start
-            kv_pages = {pos: (chunk_kv[pos]["k"][:, 0, rel:rel + ps],
-                              chunk_kv[pos]["v"][:, 0, rel:rel + ps])
-                        for pos in self._attn_pos}
-            self._seal_into(slot, j, kv_pages)
+            self._seal_into(slot, j, srcs, row=0, start=j * ps - start)
         sl.pf_start = start + valid
         sl.len = new_len
         if sl.pf_start >= plen:

@@ -1,6 +1,7 @@
 """Encode/decode roundtrip + compression-ratio tests (paper §IV-D, Eq. 1/2)."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -85,6 +86,39 @@ def test_bitfield_pack_roundtrip(nbits, seed):
     packed = packing._pack_fields(codes, nbits)
     back = packing._unpack_fields(packed, 7, nbits)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(codes))
+
+
+def _scatter_compact(values, mask, count):
+    """The scatter that ``_gather_compact`` replaced: out[rank[i]] =
+    values[i] where mask, unmasked positions parked in an overflow row."""
+    nb, w, n = values.shape
+    rank = jnp.cumsum(mask, axis=1) - mask.astype(jnp.int32)
+    tgt = jnp.where(mask, rank, count)
+    out = jnp.zeros((nb, count + 1, n), values.dtype)
+    out = out.at[jnp.arange(nb)[:, None, None], tgt,
+                 jnp.arange(n)[None, None, :]].set(values)
+    return out[:, :count, :]
+
+
+@pytest.mark.parametrize("dtype", [np.int8, np.uint8])
+@pytest.mark.parametrize("masked", ["exact", "fewer", "more"])
+def test_gather_compact_matches_the_scatter(dtype, masked):
+    """The payload compaction writes the bytes the scatter wrote: with
+    exactly ``count`` masked positions per block and column (what
+    ``pack`` gives it), fewer (rows left 0) and more (the rest dropped)."""
+    rng = np.random.default_rng(4)
+    nb, w, n, count = 5, 16, 33, 8
+    values = jnp.asarray(rng.integers(np.iinfo(dtype).min,
+                                      int(np.iinfo(dtype).max) + 1,
+                                      size=(nb, w, n)), dtype)
+    keys = rng.random((nb, w, n)).argsort(axis=1).argsort(axis=1)
+    k = {"exact": count, "fewer": count - 3, "more": count + 3}[masked]
+    mask = jnp.asarray(keys < k)
+    got = packing._gather_compact(values, mask, count)
+    assert got.dtype == values.dtype
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(_scatter_compact(values, mask,
+                                                              count)))
 
 
 def test_padding_blocks():

@@ -79,8 +79,9 @@ def test_lane_program_names_are_distinct_and_sealer_keeps_its_own(setup):
     assert len(set(names)) == len(names) and "jit_seal" not in names
     sched = BatchScheduler(cfg, params, n_slots=2, max_len=48)
     pool = sched.pools[sched._attn_pos[0]]
-    page = sched.hot[sched._attn_pos[0]]["k_tail"][:, 0]
-    low = make_sealer(sched.spec).lower(pool, page, page, jnp.int32(0))
+    tail = sched.hot[sched._attn_pos[0]]["k_tail"]
+    low = make_sealer(sched.spec).lower(pool, tail, tail,
+                                        np.zeros((3,), np.int32))
     assert _module_name(low) == "jit_seal"
 
 
