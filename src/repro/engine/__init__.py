@@ -37,6 +37,15 @@ The legacy entrypoints (``core.apply.pack_tree`` / ``fake_quantize_tree``,
 over plan construction; the old ``models.quantize.gather_dequant`` shim is
 gone — the registry's ``sharded:*`` family owns the compressed gather.
 """
+import jax
+
+# A device trace attributes ops by the scopes in their ``op_name``
+# (``pallas:onehot``, ``attn:fused``, ``head:dense``, ...).  That is
+# metadata, which JAX leaves out of the persistent compilation cache's key
+# by default, so a hit would hand back the op_names of whichever build
+# compiled the same ops first.  Keying on it keeps a program's names its own.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 from repro.engine.cache import (CacheSpec, build_cache_spec, decode_pages,
                                 encode_page, gather_decode_pages,
                                 select_cache_variant)

@@ -163,9 +163,11 @@ def embed_lookup(p: dict, ids: jnp.ndarray, dtype) -> jnp.ndarray:
 
 
 def logits(head_p: Optional[dict], embed_p: dict, x: jnp.ndarray) -> jnp.ndarray:
-    """LM head: tied (embed^T) or untied."""
-    if head_p is not None:
-        return jnp.dot(x, head_p["w"].astype(x.dtype),
+    """LM head: tied (embed^T) or untied, under the ``head:dense`` scope so
+    that a device trace attributes its ops (the scope is metadata only)."""
+    with jax.named_scope("head:dense"):
+        if head_p is not None:
+            return jnp.dot(x, head_p["w"].astype(x.dtype),
+                           preferred_element_type=jnp.float32)
+        return jnp.dot(x, embed_p["table"].astype(x.dtype).T,
                        preferred_element_type=jnp.float32)
-    return jnp.dot(x, embed_p["table"].astype(x.dtype).T,
-                   preferred_element_type=jnp.float32)

@@ -85,6 +85,53 @@ def test_lane_program_names_are_distinct_and_sealer_keeps_its_own(setup):
     assert _module_name(low) == "jit_seal"
 
 
+@pytest.mark.parametrize("arch,tied", [("olmo_1b", True),
+                                       ("qwen2_7b", False)])
+def test_decode_lane_scopes_its_head(arch, tied):
+    """The LM head, tied or untied, compiles to instructions whose op_name
+    carries ``head:dense``: the device trace attributes the head by it."""
+    cfg = get_smoke_config(arch)
+    assert cfg.tie_embeddings is tied
+    params = init_params(model_defs(cfg), seed=0)
+    assert ("lm_head" in params) is not tied
+    sched = BatchScheduler(cfg, params, n_slots=2, max_len=48)
+    text = _lower("decode", cfg, params, sched).compile().as_text()
+    scoped = [ln for ln in text.splitlines()
+              if re.search(r'op_name="[^"]*head:dense', ln)]
+    # the head's matmul itself: both slots' logits over the padded vocab
+    logits = f"f32[{sched.n_slots},{cfg.padded_vocab}]"
+    assert any(logits in ln and " dot(" in ln for ln in scoped), scoped
+
+
+def test_compile_cache_hit_keeps_its_own_scopes(tmp_path):
+    """Two programs that differ only in a scope (same function name, same
+    ops), compiled one after the other through a persistent compilation
+    cache: the second keeps its own op_names instead of loading the
+    first's from the cache."""
+    code = ("import jax, jax.numpy as jnp\n"
+            "import repro.engine\n"
+            "s = jax.ShapeDtypeStruct((4, 8), jnp.float32)\n"
+            "w = jax.ShapeDtypeStruct((8, 16), jnp.float32)\n"
+            "def head(x, w):\n"
+            "    return jnp.dot(x, w)\n"
+            "plain = head\n"
+            "def head(x, w):\n"
+            "    with jax.named_scope('head:dense'):\n"
+            "        return jnp.dot(x, w)\n"
+            "texts = [jax.jit(f).lower(s, w).compile().as_text()\n"
+            "         for f in (plain, head)]\n"
+            "assert 'head:dense' not in texts[0]\n"
+            "assert 'head:dense' in texts[1]\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [SRC] + os.environ.get("PYTHONPATH", "").split(os.pathsep)),
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=120)
+    assert os.listdir(tmp_path)         # the cache was written and read
+
+
 def _serve_two(cfg, params):
     """Request 0 (20 prompt tokens, 14 out) and request 1 (5 prompt tokens,
     4 out) on two slots, pages of 16 and chunks of 16."""

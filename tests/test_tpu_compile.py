@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler is installed with jaxlib and compiles
 against a topology that is described, not attached.  This catches what
 interpret mode cannot — Mosaic lowering gaps, unaligned tiles, scoped-VMEM
 overruns — at the widths OLMo-1B serves with (d_model 2048, d_ff 8192,
-head_dim 128, page_size 16).
+head_dim 128, page_size 16), and at Qwen2-7B's grouped query rows in the
+fused attention.
 
 The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and the test workers each
@@ -79,11 +80,12 @@ def test_onehot_compiles_at_prefill_tile(chip):
              *_packed_shapes(MIXED, k, n, chip))
 
 
-def test_fused_attention_compiles(chip):
-    """cache:attn_fused over DLIQ q=4 pages: head_dim 128, page_size 16,
-    16 KV heads, 4 decode slots x 36 pages."""
+def _compile_fused_attention(chip, b, pages, heads, rows):
+    """cache:attn_fused over DLIQ q=4 pages (head_dim 128, page_size 16):
+    ``b`` slots x ``pages`` pages, ``heads`` KV heads, ``rows`` query rows
+    per KV head."""
     kv = StruMConfig(method="dliq", p=0.5, q=4)
-    b, pages, heads, hd, ps = 4, 36, 16, 128, 16
+    hd, ps = 128, 16
     nb = ps // kv.w
     mb, nh, lb = packing.field_dims(kv.w, kv.n_low, kv.q, kv.method)
     f = heads * hd
@@ -99,5 +101,21 @@ def test_fused_attention_compiles(chip):
         return strum_paged_attention_pallas(
             q4, *rest, w=kv.w, n_low=kv.n_low, q=kv.q, method=kv.method,
             interpret=False)
-    _compile(attn, s((b, heads, 1, hd), jnp.float32), *page, *page,
+    _compile(attn, s((b, heads, rows, hd), jnp.float32), *page, *page,
              s((b, pages), jnp.int32), s((b,), jnp.int32))
+
+
+def test_fused_attention_compiles(chip):
+    """OLMo-1B's decode: 16 KV heads, one query row each, 4 decode slots x
+    36 pages."""
+    _compile_fused_attention(chip, b=4, pages=36, heads=16, rows=1)
+
+
+@pytest.mark.parametrize("b,rows", [(48, 7), (1, 896)],
+                         ids=["decode", "prefill_chunk"])
+def test_fused_attention_compiles_at_grouped_query_rows(chip, b, rows):
+    """Qwen2-7B's grouped queries: 28 query heads over 4 KV heads give 7
+    rows per KV head in decode (odd, and below the 8-row sublane tile) and
+    128 x 7 = 896 in a 128-token prefill chunk; 2048-token windows of 128
+    pages, 48 decode slots."""
+    _compile_fused_attention(chip, b=b, pages=128, heads=4, rows=rows)
