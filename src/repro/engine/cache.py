@@ -349,25 +349,41 @@ def _attn_unfused(pool, qf, page_table, n_valid, *, cfg, spec, backend=None,
 
 
 def _gather_packed(pool: dict, page_table: jnp.ndarray, keys) -> dict:
-    """Per-(slot, page) packed payload gather — the *only* HBM read of the
-    sealed pools on the fused path, and it moves packed bytes only."""
+    """Per-(slot, page) packed payload gather of every table entry, sealed
+    or not (-1 reads page 0) — the fused path's HBM read of the sealed
+    pools, in packed bytes only.  The kernel then fetches from this copy
+    only the page blocks up to each slot's last sealed page."""
     ids = jnp.clip(page_table, 0, None)
     return {k: jnp.take(pool[k], ids, axis=0) for k in keys}
 
 
 def pages_gathered(page_table_shape) -> int:
-    """Pages one sealed-page attention call reads per attention position
+    """Pages one sealed-page attention call gathers per attention position
     for a page table of ``page_table_shape`` (n_slots, pages_per_seq):
-    ``_gather_packed`` (and the unfused path's page decode) takes every
-    entry of the table, sealed or not."""
+    ``_gather_packed`` (and the unfused path's page decode) copies every
+    entry of the table, sealed or not, though the fused kernel reads back
+    only the blocks that hold sealed pages."""
     return math.prod(page_table_shape)
 
 
-def _note_fused_bytes(gk: dict, gv: dict) -> None:
-    if telemetry.enabled():
-        telemetry.inc("attn/fused/packed_bytes",
-                      sum(int(d[k].size) for d in (gk, gv) for k in d
-                          if k != "scale"))
+def _note_fused(gk: dict, gv: dict, qf, page_table, spec) -> None:
+    """Trace-time record of a fused call: the packed bytes it gathers, and
+    the kernel's grid — pages and KV heads a step, steps a call — as
+    counters and as an ``attn:fused/grid`` event inside the call's span."""
+    if not telemetry.enabled():
+        return
+    from repro.kernels.strum_attention import grid_plan
+    rows = [max(gk[k].shape[3], 1) for k in gk if k != "scale"]
+    pc, g, grid = grid_plan(qf.shape, page_table.shape[-1], spec.page_size,
+                            gk["lo"].shape[2], rows)
+    steps = math.prod(grid)
+    telemetry.inc("attn/fused/packed_bytes",
+                  sum(int(d[k].size) for d in (gk, gv) for k in d
+                      if k != "scale"))
+    telemetry.inc("attn/fused/calls")
+    telemetry.inc("attn/fused/grid_steps", steps)
+    telemetry.event("attn:fused/grid", cat="attn", pages_per_step=pc,
+                    heads_per_step=g, grid_steps=steps)
 
 
 @register_kernel(
@@ -382,7 +398,7 @@ def _attn_fused(pool, qf, page_table, n_valid, *, cfg, spec, backend=None,
     from repro.kernels.strum_attention import strum_paged_attention_pallas
     gk = _gather_packed(pool["k"], page_table, CACHE_PAYLOAD_KEYS)
     gv = _gather_packed(pool["v"], page_table, CACHE_PAYLOAD_KEYS)
-    _note_fused_bytes(gk, gv)
+    _note_fused(gk, gv, qf, page_table, spec)
     return strum_paged_attention_pallas(
         qf, gk["mask"], gk["hi"], gk["lo"], gk["scale"],
         gv["mask"], gv["hi"], gv["lo"], gv["scale"],
@@ -404,7 +420,7 @@ def _attn_fused_maskfree(pool, qf, page_table, n_valid, *, cfg, spec,
         strum_paged_attention_pallas_maskfree)
     gk = _gather_packed(pool["k"], page_table, ("lo", "scale"))
     gv = _gather_packed(pool["v"], page_table, ("lo", "scale"))
-    _note_fused_bytes(gk, gv)
+    _note_fused(gk, gv, qf, page_table, spec)
     return strum_paged_attention_pallas_maskfree(
         qf, gk["lo"], gk["scale"], gv["lo"], gv["scale"],
         page_table, n_valid, w=cfg.w, q=cfg.q, method=cfg.method,

@@ -174,7 +174,15 @@ def _place(rows: list, slot: jnp.ndarray) -> jnp.ndarray:
 
 
 def _decode_tile(mask_u8, hi_i8, lo_u8, scale_f32, *, w, n_low, q, method):
-    """Decompress one (bk, bn) weight tile in VMEM; returns f32.
+    """Decompress one (bk, bn) weight tile in VMEM; returns f32."""
+    vals = _decode_blocks(mask_u8, hi_i8, lo_u8, w=w, n_low=n_low, q=q,
+                          method=method)
+    bnb, _, bn = vals.shape
+    return vals.reshape(bnb * w, bn) * scale_f32             # (bk, bn) f32
+
+
+def _decode_blocks(mask_u8, hi_i8, lo_u8, *, w, n_low, q, method):
+    """Unscaled (bnb, w, bn) f32 values of ``bnb`` packed blocks.
 
     Every position gets a slot in the concatenated payload ``hi ++ lo``:
     its rank among the high positions, or ``n_high`` plus its rank among
@@ -191,17 +199,21 @@ def _decode_tile(mask_u8, hi_i8, lo_u8, scale_f32, *, w, n_low, q, method):
         slot = jnp.where(high, rank, n_high + pos - rank)
         rows += [_decode_low(c, method, q)
                  for c in _unpack_fields(lo_u8, n_low, q)]
-    vals = _place(rows, slot)
-    bnb, _, bn = vals.shape
-    return vals.reshape(bnb * w, bn) * scale_f32             # (bk, bn) f32
+    return _place(rows, slot)
 
 
 def _decode_tile_maskfree(lo_u8, scale_f32, *, w, q, method):
     """p = 1.0 decode: the lo fields are the whole block in position order."""
+    vals = _decode_blocks_maskfree(lo_u8, w=w, q=q, method=method)
+    bnb, _, bn = vals.shape
+    return vals.reshape(bnb * w, bn) * scale_f32
+
+
+def _decode_blocks_maskfree(lo_u8, *, w, q, method):
+    """Unscaled (bnb, w, bn) f32 values of ``bnb`` p = 1.0 blocks."""
     rows = [_decode_low(c, method, q) for c in _unpack_fields(lo_u8, w, q)]
     bnb, _, bn = lo_u8.shape
-    vals = _place(rows, lax.broadcasted_iota(jnp.int32, (bnb, w, bn), 1))
-    return vals.reshape(bnb * w, bn) * scale_f32
+    return _place(rows, lax.broadcasted_iota(jnp.int32, (bnb, w, bn), 1))
 
 
 def _kernel(x_ref, mask_ref, hi_ref, lo_ref, scale_ref, o_ref, *,
@@ -260,12 +272,14 @@ def strum_matmul_pallas(x, mask, hi, lo, scale, *, w: int, n_low: int, q: int,
     return out
 
 
-def _mosaic_params(interpret: bool, grid_rank: int = 3):
+def _mosaic_params(interpret: bool, grid_rank: int = 3,
+                   vmem_limit_bytes=None):
     if interpret:
         return None
     # all axes are parallel except the innermost reduction (k) axis
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",))
+        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _kernel_maskfree(x_ref, lo_ref, scale_ref, o_ref, *, w, q, method):

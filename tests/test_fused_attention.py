@@ -38,41 +38,43 @@ PS, KV, HD = 16, 2, 16
 FEAT = KV * HD
 
 
-def _pool(cfg, n_pages):
-    pages = RNG.normal(size=(n_pages, PS, FEAT)).astype(np.float32)
+def _pool(cfg, n_pages, feat=FEAT):
+    pages = RNG.normal(size=(n_pages, PS, feat)).astype(np.float32)
     enc = jax.vmap(lambda pg: ec.encode_page(pg, cfg))(jnp.asarray(pages))
     return pages, enc
 
 
-def _specs(cfg):
-    fused = ec.build_cache_spec(cfg, page_size=PS, feat=FEAT,
+def _specs(cfg, feat=FEAT):
+    fused = ec.build_cache_spec(cfg, page_size=PS, feat=feat,
                                 backend="interpret")
-    unfused = ec.build_cache_spec(cfg, page_size=PS, feat=FEAT,
+    unfused = ec.build_cache_spec(cfg, page_size=PS, feat=feat,
                                   backend="xla")
     return fused, unfused
 
 
-def _decode_pool(enc, cfg):
-    """(n_pages, PS, KV, HD) fp reference content of the sealed pages."""
-    spec = ec.build_cache_spec(cfg, page_size=PS, feat=FEAT, backend="xla")
+def _decode_pool(enc, cfg, kv=KV):
+    """(n_pages, PS, kv, HD) fp reference content of the sealed pages."""
+    spec = ec.build_cache_spec(cfg, page_size=PS, feat=kv * HD,
+                               backend="xla")
     dec = np.asarray(ec.decode_pages(enc, spec))
-    return dec.reshape(dec.shape[0], PS, KV, HD)
+    return dec.reshape(dec.shape[0], PS, kv, HD)
 
 
 def _oracle_partial(deck, decv, qf, table, n_valid):
-    """Dense numpy softmax partial over the sealed pages: (acc, m, l)."""
+    """Dense numpy softmax partial over the sealed, assigned pages."""
     b, kv, rep, hd = qf.shape
     acc = np.zeros((b, kv, rep, hd), np.float32)
     m = np.full((b, kv, rep), -1e30, np.float32)
     l = np.zeros((b, kv, rep), np.float32)
     for i in range(b):
-        nv = int(n_valid[i])
-        if nv == 0:
+        live = [int(table[i, j]) for j in range(int(n_valid[i]))
+                if table[i, j] >= 0]
+        if not live:
             continue
-        ks = np.concatenate([deck[int(table[i, j])] for j in range(nv)])
-        vs = np.concatenate([decv[int(table[i, j])] for j in range(nv)])
+        ks = np.concatenate([deck[p] for p in live])
+        vs = np.concatenate([decv[p] for p in live])
         for g in range(kv):
-            sc = qf[i, g] @ ks[:, g].T                     # (rep, nv*PS)
+            sc = qf[i, g] @ ks[:, g].T                     # (rep, n*PS)
             m[i, g] = sc.max(axis=-1)
             p = np.exp(sc - m[i, g][:, None])
             l[i, g] = p.sum(axis=-1)
@@ -118,25 +120,75 @@ def test_register_attn_requires_cache():
 
 # ------------------------------------------------------------ kernel parity --
 
-@pytest.mark.parametrize("label,cfg", PACKED_CODECS)
-def test_fused_matches_unfused_and_oracle(label, cfg):
+# Kernel grids beyond the original case, each with the (pages, KV heads)
+# block forced so that a small table still spans several blocks:
+# (kv heads, query rows per head, pool pages, table, n_valid, block).
+GRIDS = {
+    # block 1 of slot 0 holds a sealed and an unsealed page; P = 5 is not a
+    # multiple of Pc = 2 and n_valid = 3 is not either
+    "mixed_block": (2, 3, 6, [[0, 2, 4, 1, 3], [5, -1, -1, -1, -1]],
+                    [3, 1], (2, 2)),
+    # -1 ids inside the sealed range: a first block with no live page, and
+    # a slot whose sealed range holds none at all
+    "unassigned_live": (2, 3, 6, [[-1, -1, 2, 3], [-1, -1, -1, 5]],
+                        [4, 3], (2, 1)),
+    # a slot with nothing sealed beside a full one
+    "empty_beside_full": (2, 3, 6, [[-1, -1, -1, -1], [0, 1, 2, 3]],
+                          [0, 4], (2, 2)),
+    # MHA decode: one query row per KV head, every head in one step
+    "mha_r1": (4, 1, 8, [[0, 1, 2, 3, 4, 5], [6, 7, 3, -1, -1, -1]],
+               [5, 3], (2, 4)),
+    # GQA decode: 7 query rows per KV head
+    "gqa_r7": (2, 7, 8, [[7, 6, 5, 4, 3, 2], [0, 1, -1, -1, -1, -1]],
+               [6, 2], (4, 2)),
+    # chunked prefill: many query rows over one slot's sealed prefix
+    "prefill_r64": (2, 64, 6, [[3, 1, 4, 0, 5, -1]], [5], (4, 1)),
+}
+
+MASKFREE = StruMConfig(method="dliq", p=1.0, q=4)
+
+
+@pytest.mark.parametrize("label,cfg,grid", [
+    *(pytest.param(label, cfg, None, id=f"{label}-cfg{i}")
+      for i, (label, cfg) in enumerate(PACKED_CODECS)),
+    *(pytest.param(PACKED_CODECS[i % 3][0], PACKED_CODECS[i % 3][1], name,
+                   id=f"{PACKED_CODECS[i % 3][0]}-{name}")
+      for i, name in enumerate(GRIDS)),
+    *(pytest.param("maskfree", MASKFREE, name, id=f"maskfree-{name}")
+      for name in ("mixed_block", "unassigned_live")),
+])
+def test_fused_matches_unfused_and_oracle(label, cfg, grid, monkeypatch):
     """Fused kernel == unfused gather-then-einsum == dense numpy softmax
-    over the decoded pages, with ragged n_valid and -1 unassigned pages."""
-    _, enc = _pool(cfg, n_pages=6)
-    pool = {"k": enc, "v": _pool(cfg, n_pages=6)[1]}
-    fused_spec, unfused_spec = _specs(cfg)
-    qf = jnp.asarray(RNG.normal(size=(2, KV, 3, HD)), jnp.float32)
-    table = jnp.array([[0, 2, 4], [5, -1, -1]], jnp.int32)
-    n_valid = jnp.array([3, 1], jnp.int32)
+    over the decoded pages, with ragged n_valid and -1 unassigned pages;
+    the ``grid`` cases also force blocks smaller than the table."""
+    from repro.kernels import strum_attention
+    if grid is None:
+        kv, rows, n_pages = KV, 3, 6
+        table, n_valid = [[0, 2, 4], [5, -1, -1]], [3, 1]
+    else:
+        kv, rows, n_pages, table, n_valid, block = GRIDS[grid]
+        monkeypatch.setattr(strum_attention, "block_shape",
+                            lambda *a, **k: block)
+    feat = kv * HD
+    pool = {"k": _pool(cfg, n_pages, feat)[1],
+            "v": _pool(cfg, n_pages, feat)[1]}
+    fused_spec, unfused_spec = _specs(cfg, feat)
+    if cfg is MASKFREE:
+        assert fused_spec.attn_variant == "cache:attn_fused_maskfree"
+    qf = jnp.asarray(RNG.normal(size=(len(table), kv, rows, HD)),
+                     jnp.float32)
+    table = jnp.array(table, jnp.int32)
+    n_valid = jnp.array(n_valid, jnp.int32)
 
     fused = ec.attn_sealed_partial(pool, qf, table, n_valid, fused_spec)
     unfused = ec.attn_sealed_partial(pool, qf, table, n_valid, unfused_spec)
     for a, b in zip(fused, unfused):
+        assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
 
-    deck = _decode_pool(pool["k"], cfg)
-    decv = _decode_pool(pool["v"], cfg)
+    deck = _decode_pool(pool["k"], cfg, kv)
+    decv = _decode_pool(pool["v"], cfg, kv)
     o_acc, o_m, o_l = _oracle_partial(deck, decv, np.asarray(qf),
                                       np.asarray(table), np.asarray(n_valid))
     got_acc = np.asarray(fused[0])
@@ -146,6 +198,61 @@ def test_fused_matches_unfused_and_oracle(label, cfg):
     ref = o_acc / np.maximum(o_l, 1e-30)[..., None]
     got = got_acc / np.maximum(got_l, 1e-30)[..., None]
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
+
+
+# The serving cells' shapes: (query rows per KV head, KV heads, pages);
+# head_dim 128, 16-position pages of DLIQ q=4 w=16 (mask, hi, lo rows).
+CELL_SHAPES = {
+    "olmo_decode": (1, 16, 128), "olmo_prefill_chunk": (128, 16, 128),
+    "qwen2_decode": (7, 4, 128), "qwen2_prefill_chunk": (896, 4, 128),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_SHAPES)
+def test_block_shape_fits_budget_and_divides_heads(cell):
+    """The (pages, KV heads) block chosen from a call's shapes divides the
+    heads, stays within the table and the VMEM budget it declares, and
+    folds several pages and heads wherever the budget allows."""
+    from repro.kernels import strum_attention as sa
+    r, kv, pages = CELL_SHAPES[cell]
+    rows = (2, 8, 4)
+    pc, g = sa.block_shape(r, kv, 128, pages, 16, 1, rows)
+    assert kv % g == 0 and 1 <= pc <= pages
+    assert sa._vmem_bytes(pc, g, r, 128, 16, 1, rows) <= sa.VMEM_BUDGET
+    assert sa.VMEM_BUDGET <= sa.VMEM_LIMIT
+    assert pc * g >= 8
+    if r < 8:                         # decode folds every head
+        assert g == kv
+    # a smaller budget never picks a larger block
+    pc2, g2 = sa.block_shape(r, kv, 128, pages, 16, 1, rows,
+                             budget=sa.VMEM_BUDGET // 4)
+    assert pc2 * g2 <= pc * g
+
+
+def test_fused_call_records_its_grid():
+    """A traced fused call counts its grid steps and records the block it
+    chose (pages and KV heads a step) inside its ``attn:fused`` span."""
+    from repro import telemetry
+    from repro.kernels.strum_attention import block_shape
+    cfg = PACKED_CODECS[0][1]
+    _, enc = _pool(cfg, n_pages=4)
+    pool = {"k": enc, "v": enc}
+    fused_spec, _ = _specs(cfg)
+    qf = jnp.asarray(RNG.normal(size=(2, KV, 1, HD)), jnp.float32)
+    table = jnp.array([[0, 1, 2], [3, -1, -1]], jnp.int32)
+    with telemetry.recording() as rec:
+        ec.attn_sealed_partial(pool, qf, table, jnp.array([2, 1], jnp.int32),
+                               fused_spec)
+    pc, g = block_shape(1, KV, HD, 3, PS, 1, (2, 8, 4))
+    steps = 2 * (KV // g) * -(-3 // pc)
+    assert rec.counter("attn/fused/calls") == 1
+    assert rec.counter("attn/fused/grid_steps") == steps
+    (grid,) = [e for e in rec.chrome_trace()["traceEvents"]
+               if e["name"] == "attn:fused/grid"]
+    assert grid["args"] == {"pages_per_step": pc, "heads_per_step": g,
+                            "grid_steps": steps}
+    (span,) = rec.spans("attn:fused")
+    assert span["ts"] <= grid["ts"] <= span["ts"] + span["dur"]
 
 
 def test_empty_sealed_prefix():

@@ -111,11 +111,13 @@ def test_fused_attention_compiles(chip):
     _compile_fused_attention(chip, b=4, pages=36, heads=16, rows=1)
 
 
-@pytest.mark.parametrize("b,rows", [(48, 7), (1, 896)],
-                         ids=["decode", "prefill_chunk"])
-def test_fused_attention_compiles_at_grouped_query_rows(chip, b, rows):
+@pytest.mark.parametrize("b,heads,rows", [(48, 4, 7), (1, 4, 896),
+                                          (1, 16, 128)],
+                         ids=["decode", "prefill_chunk", "olmo_prefill_chunk"])
+def test_fused_attention_compiles_at_grouped_query_rows(chip, b, heads, rows):
     """Qwen2-7B's grouped queries: 28 query heads over 4 KV heads give 7
     rows per KV head in decode (odd, and below the 8-row sublane tile) and
     128 x 7 = 896 in a 128-token prefill chunk; 2048-token windows of 128
-    pages, 48 decode slots."""
-    _compile_fused_attention(chip, b=b, pages=128, heads=4, rows=rows)
+    pages, 48 decode slots.  Beside them OLMo-1B's 128-row prefill chunk
+    over 16 KV heads, whose block folds fewer heads than its decode's."""
+    _compile_fused_attention(chip, b=b, pages=128, heads=heads, rows=rows)
