@@ -32,6 +32,11 @@ LANE_PROGRAMS = {
 }
 
 
+def _rules(mesh, rules: Optional[Rules]) -> Optional[Rules]:
+    """The caller's sharding rules, else the mesh's defaults, else none."""
+    return rules or (rules_for_mesh(mesh) if mesh is not None else None)
+
+
 def build_serving_plan(params, *, schedule=None, cfg=None, policy=None,
                        backend: Optional[str] = None, mesh=None,
                        rules: Optional[Rules] = None):
@@ -45,17 +50,16 @@ def build_serving_plan(params, *, schedule=None, cfg=None, policy=None,
     device serves the FSDP×TP mesh with compressed gathers.
     """
     from repro import engine
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
     return engine.build_plan(params, schedule=schedule, cfg=cfg,
                              policy=policy, backend=backend, mesh=mesh,
-                             rules=rules)
+                             rules=_rules(mesh, rules))
 
 
 def make_train_step(cfg, opt_cfg: adamw.AdamWConfig, mesh=None,
                     rules: Optional[Rules] = None,
                     grad_compression: bool = False):
     """(params, opt_state[, ef_state], batch) -> updated state + metrics."""
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
+    rules = _rules(mesh, rules)
 
     def loss(params, batch):
         return tfm.loss_fn(params, batch, cfg, mesh=mesh, rules=rules)
@@ -82,7 +86,7 @@ def make_train_step(cfg, opt_cfg: adamw.AdamWConfig, mesh=None,
 
 
 def make_prefill_step(cfg, mesh=None, rules: Optional[Rules] = None):
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
+    rules = _rules(mesh, rules)
 
     def prefill_step(params, batch):
         return tfm.prefill(params, batch, cfg, mesh=mesh, rules=rules)
@@ -90,7 +94,7 @@ def make_prefill_step(cfg, mesh=None, rules: Optional[Rules] = None):
 
 
 def make_decode_step(cfg, mesh=None, rules: Optional[Rules] = None):
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
+    rules = _rules(mesh, rules)
 
     def dense_decode_step(params, token, caches, cache_len):
         return tfm.decode_step(params, token, caches, cache_len, cfg,
@@ -104,7 +108,7 @@ def make_paged_decode_step(cfg, spec, mesh=None,
     """Decode lane of the paged serving runtime: one (n_slots, 1) step over
     page-table caches.  ``spec`` (a :class:`repro.engine.cache.CacheSpec`)
     rides the closure as static codec metadata."""
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
+    rules = _rules(mesh, rules)
 
     def decode_step(params, token, pools, hot, cache_len, page_table, active):
         return tfm.decode_step_paged(params, token, pools, hot, cache_len,
@@ -120,7 +124,7 @@ def make_chunked_prefill_step(cfg, spec, mesh=None,
     """Prefill lane: one fixed-shape (1, chunk) step that any slot's prompt
     advances through — the single prefill executable that replaces the old
     compile-per-prompt-length path."""
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
+    rules = _rules(mesh, rules)
 
     def prefill_chunk_step(params, tokens, pools, hot, page_table, slot,
                            start, valid_len):
@@ -137,7 +141,7 @@ def make_verify_step(cfg, spec, mesh=None, rules: Optional[Rules] = None,
     step that scores a slot's draft window at full fidelity without
     mutating any cache state — the scheduler commits accepted KV rows
     itself (its rollback)."""
-    rules = rules or (rules_for_mesh(mesh) if mesh is not None else None)
+    rules = _rules(mesh, rules)
 
     def verify_step(params, tokens, pools, hot, page_table, slot, start):
         return tfm.verify_chunk_step(params, tokens, pools, hot, page_table,

@@ -219,6 +219,24 @@ def _merge_partials(parts):
     return acc_tot / jnp.maximum(l_tot, 1e-30)[..., None]
 
 
+def _fp_partial(q, k, v, valid):
+    """Masked online-softmax partial over fp keys/values (no pool).
+
+    q (B, KV, R, hd) f32, pre-scaled; k/v (B, P, KV, hd); ``valid``
+    broadcasts to the scores (B, KV, R, P).  Returns the unnormalized
+    ``(acc, m, l)`` of :func:`_merge_partials`.  A row with no valid key
+    scores exp(0) everywhere, so the select zeroes it: (0, NEG_INF, 0)
+    then merges as nothing.
+    """
+    k = k.astype(jnp.float32)
+    v = v.astype(jnp.float32)
+    sc = jnp.where(valid, jnp.einsum("bgrd,bpgd->bgrp", q, k), NEG_INF)
+    m = jnp.max(sc, axis=-1)
+    pexp = jnp.where(valid, jnp.exp(sc - m[..., None]), 0.0)
+    l = jnp.sum(pexp, axis=-1)
+    return jnp.einsum("bgrp,bpgd->bgrd", pexp, v), m, l
+
+
 def decode_attention_paged(p: dict, x: jnp.ndarray, cfg, pool: dict,
                            tails: tuple, spec, page_table: jnp.ndarray,
                            cache_len: jnp.ndarray, cache_backend=None, **kw):
@@ -261,20 +279,50 @@ def decode_attention_paged(p: dict, x: jnp.ndarray, cfg, pool: dict,
     # holds absolute position n_valid * ps + i
     t_pos = (n_valid * ps)[:, None] + jnp.arange(ps)[None, :]   # (B, ps)
     valid_t = (t_pos <= cache_len[:, None])[:, None, None, :]
-    kt_f = new_kt.astype(jnp.float32)                           # (B,ps,KV,hd)
-    vt_f = new_vt.astype(jnp.float32)
-    sc_t = jnp.einsum("bgrd,bpgd->bgrp", qf, kt_f)
-    sc_t = jnp.where(valid_t, sc_t, NEG_INF)
-    m_t = jnp.max(sc_t, axis=-1)                                # finite: the
-    pexp = jnp.exp(sc_t - m_t[..., None])                       # fresh token
-    pexp = jnp.where(valid_t, pexp, 0.0)                        # is valid
-    l_t = jnp.sum(pexp, axis=-1)
-    acc_t = jnp.einsum("bgrp,bpgd->bgrd", pexp, vt_f)
+    tail = _fp_partial(qf, new_kt, new_vt, valid_t)
 
-    o = _merge_partials([sealed, (acc_t, m_t, l_t)])            # (B,KV,R,hd)
+    o = _merge_partials([sealed, tail])                         # (B,KV,R,hd)
     o = o.reshape(b, 1, nh * hd).astype(x.dtype)
     y = linear(p["wo"], o, **dict(kw, tp_pattern="row"))
     return y, (new_kt, new_vt)
+
+
+def _chunk_attention(p, x, cfg, pool, tails, spec, table_row, start,
+                     cache_backend=None, **kw):
+    """Attention of ONE slot's (1, C) rows at positions ``start + [0, C)``:
+    the sealed pages' partial, then (with ``tails``) the hot tail's
+    committed rows, then the chunk against itself, intra-chunk causal.
+    Returns ``(y, (k, v))``."""
+    from repro.engine.cache import attn_sealed_partial
+    b, c, _ = x.shape
+    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    rep = nh // nkv
+    ps = spec.page_size
+    positions = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
+    positions = jnp.broadcast_to(positions, (b, c))
+    q, k, v = _qkv(p, x, cfg, positions, **kw)
+
+    qf5 = (q.astype(jnp.float32) / math.sqrt(hd)).reshape(b, c, nkv, rep, hd)
+    # kernel R axis = (chunk row, rep) flattened: row i <-> (i // rep, i % rep)
+    qr = qf5.transpose(0, 2, 1, 3, 4).reshape(b, nkv, c * rep, hd)
+    n_valid = jnp.broadcast_to(start // ps, (b,)).astype(jnp.int32)
+    parts = [attn_sealed_partial(pool, qr, table_row[None, :], n_valid, spec,
+                                 backend=cache_backend)]
+    if tails is not None:
+        # tail index i holds absolute position (start // ps) * ps + i; rows
+        # at/after ``start`` may be stale draft KV and must not score
+        t_pos = (start // ps) * ps + jnp.arange(ps)
+        parts.append(_fp_partial(qr, *tails,
+                                 (t_pos < start)[None, None, None, :]))
+    causal = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]   # (cq, ck)
+    causal = jnp.broadcast_to(causal[:, None], (c, rep, c)).reshape(-1, c)
+    parts.append(_fp_partial(qr, k, v, causal[None, None]))
+
+    o = _merge_partials(parts)                          # (b, KV, c*rep, hd)
+    o = o.reshape(b, nkv, c, rep, hd).transpose(0, 2, 1, 3, 4)
+    o = o.reshape(b, c, nh * hd).astype(x.dtype)
+    y = linear(p["wo"], o, **dict(kw, tp_pattern="row"))
+    return y, (k, v)
 
 
 def prefill_attention_paged(p: dict, x: jnp.ndarray, cfg, pool: dict,
@@ -293,39 +341,8 @@ def prefill_attention_paged(p: dict, x: jnp.ndarray, cfg, pool: dict,
     ``(y, (k, v))`` with k/v (1, C, KV, hd) — writing them into pages/tail
     is the caller's job.
     """
-    from repro.engine.cache import attn_sealed_partial
-    b, c, _ = x.shape
-    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    rep = nh // nkv
-    ps = spec.page_size
-    positions = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
-    positions = jnp.broadcast_to(positions, (b, c))
-    q, k, v = _qkv(p, x, cfg, positions, **kw)
-
-    qf5 = (q.astype(jnp.float32) / math.sqrt(hd)).reshape(b, c, nkv, rep, hd)
-    # kernel R axis = (chunk row, rep) flattened: row i <-> (i // rep, i % rep)
-    qr = qf5.transpose(0, 2, 1, 3, 4).reshape(b, nkv, c * rep, hd)
-    n_valid = jnp.broadcast_to(start // ps, (b,)).astype(jnp.int32)
-    sealed = attn_sealed_partial(pool, qr, table_row[None, :], n_valid, spec,
-                                 backend=cache_backend)
-
-    # fp epilogue: the chunk against itself, intra-chunk causal
-    kf = k.astype(jnp.float32)                                  # (b,c,KV,hd)
-    vf = v.astype(jnp.float32)
-    causal = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]   # (cq, ck)
-    sc_c = jnp.einsum("bqgrd,bkgd->bgqrk", qf5, kf)
-    sc_c = jnp.where(causal[None, None, :, None, :], sc_c, NEG_INF)
-    sc_c = sc_c.reshape(b, nkv, c * rep, c)
-    m_c = jnp.max(sc_c, axis=-1)            # finite: the diagonal is valid
-    pexp = jnp.exp(sc_c - m_c[..., None])   # NEG_INF rows underflow to 0
-    l_c = jnp.sum(pexp, axis=-1)
-    acc_c = jnp.einsum("bgik,bkgd->bgid", pexp, vf)
-
-    o = _merge_partials([sealed, (acc_c, m_c, l_c)])    # (b, KV, c*rep, hd)
-    o = o.reshape(b, nkv, c, rep, hd).transpose(0, 2, 1, 3, 4)
-    o = o.reshape(b, c, nh * hd).astype(x.dtype)
-    y = linear(p["wo"], o, **dict(kw, tp_pattern="row"))
-    return y, (k, v)
+    return _chunk_attention(p, x, cfg, pool, None, spec, table_row, start,
+                            cache_backend, **kw)
 
 
 def verify_attention_paged(p: dict, x: jnp.ndarray, cfg, pool: dict,
@@ -335,58 +352,14 @@ def verify_attention_paged(p: dict, x: jnp.ndarray, cfg, pool: dict,
 
     Like :func:`prefill_attention_paged`, but ``start`` (the slot's
     committed length) is NOT page-aligned: the committed prefix splits
-    into ``start // ps`` sealed pages plus a partially-filled hot tail, so
-    the merge takes THREE online-softmax partials — sealed pages, the
-    tail's committed rows (tail index ``i`` holds absolute position
-    ``(start // ps) * ps + i``, valid *strictly* below ``start``; rows
-    at/after ``start`` may be stale draft KV and must not score), and the
-    intra-chunk causal block at query positions ``start + [0, C)``.  Every
-    sealed page and committed tail row precedes every query, so only the
-    chunk partial needs a causal mask.  Nothing is mutated — the caller
-    commits accepted rows of the returned ``(k, v)`` itself.
+    into ``start // ps`` sealed pages plus a partially-filled hot tail
+    ``tails`` (1, ps, KV, hd), so the merge takes THREE online-softmax
+    partials — sealed pages, the tail's committed rows (valid *strictly*
+    below ``start``; empty when ``start`` is page-aligned, an exact no-op
+    of the merge), and the intra-chunk causal block.  Every sealed page and
+    committed tail row precedes every query, so only the chunk partial
+    needs a causal mask.  Nothing is mutated — the caller commits accepted
+    rows of the returned ``(k, v)`` itself.
     """
-    from repro.engine.cache import attn_sealed_partial
-    b, c, _ = x.shape
-    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    rep = nh // nkv
-    ps = spec.page_size
-    positions = (start + jnp.arange(c, dtype=jnp.int32))[None, :]
-    positions = jnp.broadcast_to(positions, (b, c))
-    q, k, v = _qkv(p, x, cfg, positions, **kw)
-
-    qf5 = (q.astype(jnp.float32) / math.sqrt(hd)).reshape(b, c, nkv, rep, hd)
-    qr = qf5.transpose(0, 2, 1, 3, 4).reshape(b, nkv, c * rep, hd)
-    n_valid = jnp.broadcast_to(start // ps, (b,)).astype(jnp.int32)
-    sealed = attn_sealed_partial(pool, qr, table_row[None, :], n_valid, spec,
-                                 backend=cache_backend)
-
-    # committed hot-tail prefix (empty when start is page-aligned: the
-    # all-masked partial merges to an exact no-op, see _merge_partials)
-    kt, vt = tails                                          # (1, ps, KV, hd)
-    t_pos = (start // ps) * ps + jnp.arange(ps)
-    valid_t = (t_pos < start)[None, None, None, :]
-    sc_t = jnp.einsum("bgid,bpgd->bgip", qr, kt.astype(jnp.float32))
-    sc_t = jnp.where(valid_t, sc_t, NEG_INF)
-    m_t = jnp.max(sc_t, axis=-1)
-    pexp_t = jnp.exp(sc_t - m_t[..., None])
-    pexp_t = jnp.where(valid_t, pexp_t, 0.0)
-    l_t = jnp.sum(pexp_t, axis=-1)
-    acc_t = jnp.einsum("bgip,bpgd->bgid", pexp_t, vt.astype(jnp.float32))
-
-    # the chunk against itself, intra-chunk causal (as in prefill)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    causal = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
-    sc_c = jnp.einsum("bqgrd,bkgd->bgqrk", qf5, kf)
-    sc_c = jnp.where(causal[None, None, :, None, :], sc_c, NEG_INF)
-    sc_c = sc_c.reshape(b, nkv, c * rep, c)
-    m_c = jnp.max(sc_c, axis=-1)            # finite: the diagonal is valid
-    pexp = jnp.exp(sc_c - m_c[..., None])
-    l_c = jnp.sum(pexp, axis=-1)
-    acc_c = jnp.einsum("bgik,bkgd->bgid", pexp, vf)
-
-    o = _merge_partials([sealed, (acc_t, m_t, l_t), (acc_c, m_c, l_c)])
-    o = o.reshape(b, nkv, c, rep, hd).transpose(0, 2, 1, 3, 4)
-    o = o.reshape(b, c, nh * hd).astype(x.dtype)
-    y = linear(p["wo"], o, **dict(kw, tp_pattern="row"))
-    return y, (k, v)
+    return _chunk_attention(p, x, cfg, pool, tails, spec, table_row, start,
+                            cache_backend, **kw)

@@ -8,6 +8,12 @@ P=1 for uniform stacks).  Parameters are stacked with a leading
 single ``lax.scan`` over groups whose body unrolls the P positions — HLO
 size stays O(P), compile time stays flat in depth, and remat wraps the
 group body.
+
+Every lane — train, the monolithic prefill/decode, and the paged serving
+lanes — is the same block body (:func:`_block`, norm → mixer → residual →
+FFN → residual) run by one stack runner (:func:`_stack`); a lane differs
+only in its mixer adapter, the closure that runs its attention or SSM call
+at one layer position and returns that position's lane output.
 """
 from __future__ import annotations
 
@@ -71,22 +77,20 @@ def model_defs(cfg) -> dict:
     return defs
 
 
-
-def _scan_groups(body, x, blocks, cfg, collect=False):
+def _scan_groups(body, x, xs, cfg):
     """lax.scan over stacked layer groups, or a python unroll when
     cfg.scan_layers is False (used by the dry-run cost extrapolation —
     XLA's cost_analysis counts loop bodies once)."""
     if cfg.scan_layers:
-        return jax.lax.scan(body, x, blocks)
-    g = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        return jax.lax.scan(body, x, xs)
+    g = jax.tree_util.tree_leaves(xs)[0].shape[0]
     ys = []
     for i in range(g):
-        gp = jax.tree.map(lambda a, _i=i: a[_i], blocks)
+        gp = jax.tree.map(lambda a, _i=i: a[_i], xs)
         x, y = body(x, gp)
         ys.append(y)
     stack = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
     return x, stack
-
 
 
 def _remat(body, cfg):
@@ -106,61 +110,94 @@ def _remat(body, cfg):
 
 # ------------------------------------------------------------- forwards --
 
-def _block_apply(bp: dict, x, cfg, positions, mesh, rules, kw):
-    aux = jnp.zeros((), jnp.float32)
-    h = apply_norm(bp["norm1"], x, cfg)
-    if "attn" in bp:
-        h = attn_mod.attention(bp["attn"], h, cfg, positions, rules=rules, **kw)
-    else:
-        h = mamba2.ssm_apply(bp["ssm"], h, cfg, **kw)
-    x = x + h
-    x = constrain(x, ("batch", None, None), rules)
+def _common_kw(cfg, mesh, kw):
+    kw.setdefault("strum", cfg.strum)
+    kw.setdefault("accum_dtype", cfg.accum_dtype)
+    if mesh is not None:
+        # packed leaves (cfg.strum OR a schedule-built plan) need the mesh
+        # context for the sharded:* gather path; dense leaves ignore it
+        kw.setdefault("tp_mesh", mesh)
+    return kw
+
+
+def _block(bp: dict, per, x, mixer, cfg, mesh, rules, kw):
+    """One layer: norm1 → ``mixer(bp, h, *per)`` → residual → norm2 → MoE
+    or MLP → residual.  ``per`` are the lane's inputs at this layer.
+    Returns ``(x, aux, out)``: ``aux`` the MoE router loss (None at a
+    dense FFN), ``out`` whatever the lane's mixer returned beside its
+    activations."""
+    h, out = mixer(bp, apply_norm(bp["norm1"], x, cfg), *per)
+    x = constrain(x + h, ("batch", None, None), rules)
+    aux = None
     if cfg.d_ff > 0:
         h = apply_norm(bp["norm2"], x, cfg)
         if "moe" in bp:
             h, aux = moe.moe_apply(bp["moe"], h, cfg, mesh=mesh, **kw)
         else:
             h = mlp(bp["mlp"], h, cfg, **kw)
-        x = x + h
-        x = constrain(x, ("batch", None, None), rules)
-    return x, aux
+        x = constrain(x + h, ("batch", None, None), rules)
+    return x, aux, out
 
 
-def _embed_in(params, batch, cfg):
-    if "embeds" in batch:            # modality-stub frontends (audio / vlm)
-        return batch["embeds"].astype(cfg.activation_dtype)
-    return embed_lookup(params["embed"], batch["tokens"], cfg.activation_dtype)
+def _stack(params, x, cfg, mixer, xs, mesh, rules, kw, remat=False):
+    """Every layer, as one scan over groups of ``period(cfg)`` blocks.
+
+    ``xs`` are the lane's per-layer trees (group-stacked, keyed by period
+    position, e.g. ``(pools, hot)``); the scan slices them with the blocks
+    and position ``i`` calls ``mixer(bp, h, *(t[f"pos{i}"] for t in xs))``.
+    Returns ``(x, (outs, aux))``: the mixers' outputs keyed by position and
+    the per-group sum of MoE losses (None without MoE), group-stacked.
+    """
+    def group(x, g):
+        gp, *per = g
+        aux, outs = None, {}
+        for i in range(period(cfg)):
+            key = f"pos{i}"
+            x, a, outs[key] = _block(gp[key], [t[key] for t in per], x,
+                                     mixer, cfg, mesh, rules, kw)
+            if a is not None:
+                aux = a if aux is None else aux + a
+        return x, (outs, aux)
+
+    body = _remat(group, cfg) if remat else group
+    return _scan_groups(body, x, (params["blocks"],) + tuple(xs), cfg)
+
+
+def _embed(params, tokens, cfg):
+    """(B, S) token ids through the embedding, or (B, S, D) embeddings from
+    a modality-stub frontend (audio / vlm) as they are."""
+    if tokens.ndim == 3:
+        return tokens.astype(cfg.activation_dtype)
+    return embed_lookup(params["embed"], tokens, cfg.activation_dtype)
+
+
+def _head(params, x, cfg, last=False):
+    """Final norm, then logits (of the last position only with ``last``)."""
+    x = apply_norm(params["final_norm"], x, cfg)
+    return logits(params.get("lm_head"), params["embed"],
+                  x[:, -1:, :] if last else x)
 
 
 def forward_train(params: dict, batch: dict, cfg, mesh=None,
                   rules: Optional[Rules] = None, **kw):
     """Full forward; returns (logits_f32, total_aux)."""
-    kw.setdefault("strum", cfg.strum)
-    kw.setdefault("accum_dtype", cfg.accum_dtype)
-    if mesh is not None:
-        # thread mesh context unconditionally: packed leaves (from cfg.strum
-        # OR a schedule-built plan, where cfg.strum is None) need it for the
-        # sharded:* gather path; dense leaves ignore tp_mesh entirely
-        kw.setdefault("tp_mesh", mesh)
-    x = _embed_in(params, batch, cfg)
+    kw = _common_kw(cfg, mesh, kw)
+    x = _embed(params, batch.get("embeds", batch.get("tokens")), cfg)
     b, s, _ = x.shape
     x = constrain(x, ("batch", None, None), rules)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    p = period(cfg)
 
-    def group(x, gp):
-        aux = jnp.zeros((), jnp.float32)
-        for i in range(p):
-            x, a = _block_apply(gp[f"pos{i}"], x, cfg, positions, mesh, rules, kw)
-            aux = aux + a
-        return x, aux
+    def mixer(bp, h):
+        if "attn" in bp:
+            return attn_mod.attention(bp["attn"], h, cfg, positions,
+                                      rules=rules, **kw), None
+        return mamba2.ssm_apply(bp["ssm"], h, cfg, **kw), None
 
-    body = _remat(group, cfg)
-    x, auxs = _scan_groups(body, x, params["blocks"], cfg)
-    x = apply_norm(params["final_norm"], x, cfg)
-    lg = logits(params.get("lm_head"), params["embed"], x)
-    lg = constrain(lg, ("batch", None, "vocab"), rules)
-    return lg, jnp.sum(auxs)
+    x, (_, auxs) = _stack(params, x, cfg, mixer, (), mesh, rules, kw,
+                          remat=True)
+    lg = constrain(_head(params, x, cfg), ("batch", None, "vocab"), rules)
+    aux = jnp.zeros((), jnp.float32) if auxs is None else jnp.sum(auxs)
+    return lg, aux
 
 
 def loss_fn(params, batch, cfg, mesh=None, rules=None, **kw):
@@ -206,52 +243,27 @@ def prefill(params: dict, batch: dict, cfg, mesh=None, rules=None, **kw):
 
     Attention layers emit their (k, v); ssm layers their (conv tail, state).
     """
-    kw.setdefault("strum", cfg.strum)
-    kw.setdefault("accum_dtype", cfg.accum_dtype)
-    if mesh is not None:
-        # thread mesh context unconditionally: packed leaves (from cfg.strum
-        # OR a schedule-built plan, where cfg.strum is None) need it for the
-        # sharded:* gather path; dense leaves ignore tp_mesh entirely
-        kw.setdefault("tp_mesh", mesh)
-    x = _embed_in(params, batch, cfg)
+    kw = _common_kw(cfg, mesh, kw)
+    x = _embed(params, batch.get("embeds", batch.get("tokens")), cfg)
     b, s, _ = x.shape
     x = constrain(x, ("batch", None, None), rules)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    p = period(cfg)
+    act = cfg.activation_dtype
+    seq = ("batch", "cache_seq", None, None)
 
-    def group(x, gp):
-        caches = {}
-        for i in range(p):
-            bp = gp[f"pos{i}"]
-            h = apply_norm(bp["norm1"], x, cfg)
-            if "attn" in bp:
-                h, (k, v) = attn_mod.attention(bp["attn"], h, cfg, positions,
-                                               return_kv=True, rules=rules, **kw)
-                caches[f"pos{i}"] = {"k": constrain(k.astype(cfg.activation_dtype),
-                                                    ("batch", "cache_seq", None, None), rules),
-                                     "v": constrain(v.astype(cfg.activation_dtype),
-                                                    ("batch", "cache_seq", None, None), rules)}
-            else:
-                h, (conv_tail, hT) = mamba2.ssm_apply(bp["ssm"], h, cfg,
-                                                      return_state=True, **kw)
-                caches[f"pos{i}"] = {"conv": conv_tail.astype(cfg.activation_dtype),
-                                     "state": hT}
-            x = x + h
-            if cfg.d_ff > 0:
-                h = apply_norm(bp["norm2"], x, cfg)
-                if "moe" in bp:
-                    h, _ = moe.moe_apply(bp["moe"], h, cfg, mesh=mesh, **kw)
-                else:
-                    h = mlp(bp["mlp"], h, cfg, **kw)
-                x = x + h
-            x = constrain(x, ("batch", None, None), rules)
-        return x, caches
+    def mixer(bp, h):
+        if "attn" in bp:
+            h, (k, v) = attn_mod.attention(bp["attn"], h, cfg, positions,
+                                           return_kv=True, rules=rules, **kw)
+            return h, {"k": constrain(k.astype(act), seq, rules),
+                       "v": constrain(v.astype(act), seq, rules)}
+        h, (conv_tail, hT) = mamba2.ssm_apply(bp["ssm"], h, cfg,
+                                              return_state=True, **kw)
+        return h, {"conv": conv_tail.astype(act), "state": hT}
 
-    body = _remat(group, cfg)
-    x, caches = _scan_groups(body, x, params["blocks"], cfg)
-    x = apply_norm(params["final_norm"], x, cfg)
-    lg = logits(params.get("lm_head"), params["embed"], x[:, -1:, :])
-    return lg, caches
+    x, (caches, _) = _stack(params, x, cfg, mixer, (), mesh, rules, kw,
+                            remat=True)
+    return _head(params, x, cfg, last=True), caches
 
 
 def decode_step(params: dict, token: jnp.ndarray, caches: dict,
@@ -260,50 +272,21 @@ def decode_step(params: dict, token: jnp.ndarray, caches: dict,
 
     Returns (logits (B, 1, V), new caches).
     """
-    kw.setdefault("strum", cfg.strum)
-    kw.setdefault("accum_dtype", cfg.accum_dtype)
-    if mesh is not None:
-        # thread mesh context unconditionally: packed leaves (from cfg.strum
-        # OR a schedule-built plan, where cfg.strum is None) need it for the
-        # sharded:* gather path; dense leaves ignore tp_mesh entirely
-        kw.setdefault("tp_mesh", mesh)
-    if token.ndim == 3:
-        x = token.astype(cfg.activation_dtype)
-    else:
-        x = embed_lookup(params["embed"], token, cfg.activation_dtype)
-    x = constrain(x, ("batch", None, None), rules)
-    p = period(cfg)
+    kw = _common_kw(cfg, mesh, kw)
+    x = constrain(_embed(params, token, cfg), ("batch", None, None), rules)
 
-    def group(carry, xs):
-        x = carry
-        gp, gc = xs
-        new_c = {}
-        for i in range(p):
-            bp, c = gp[f"pos{i}"], gc[f"pos{i}"]
-            h = apply_norm(bp["norm1"], x, cfg)
-            if "attn" in bp:
-                h, (nk, nv) = attn_mod.decode_attention(
-                    bp["attn"], h, cfg, (c["k"], c["v"]), cache_len, **kw)
-                new_c[f"pos{i}"] = {"k": nk, "v": nv}
-            else:
-                h, (ncv, nst) = mamba2.ssm_decode(
-                    bp["ssm"], h, cfg, (c["conv"], c["state"]), **kw)
-                new_c[f"pos{i}"] = {"conv": ncv, "state": nst}
-            x = x + h
-            if cfg.d_ff > 0:
-                h = apply_norm(bp["norm2"], x, cfg)
-                if "moe" in bp:
-                    h, _ = moe.moe_apply(bp["moe"], h, cfg, mesh=mesh, **kw)
-                else:
-                    h = mlp(bp["mlp"], h, cfg, **kw)
-                x = x + h
-            x = constrain(x, ("batch", None, None), rules)
-        return x, new_c
+    def mixer(bp, h, c):
+        if "attn" in bp:
+            h, (nk, nv) = attn_mod.decode_attention(
+                bp["attn"], h, cfg, (c["k"], c["v"]), cache_len, **kw)
+            return h, {"k": nk, "v": nv}
+        h, (ncv, nst) = mamba2.ssm_decode(
+            bp["ssm"], h, cfg, (c["conv"], c["state"]), **kw)
+        return h, {"conv": ncv, "state": nst}
 
-    x, new_caches = _scan_groups(group, x, (params["blocks"], caches), cfg)
-    x = apply_norm(params["final_norm"], x, cfg)
-    lg = logits(params.get("lm_head"), params["embed"], x)
-    return lg, new_caches
+    x, (new_caches, _) = _stack(params, x, cfg, mixer, (caches,), mesh,
+                                rules, kw)
+    return _head(params, x, cfg), new_caches
 
 
 # -------------------------------------------------------- paged serving --
@@ -316,16 +299,6 @@ def decode_step(params: dict, token: jnp.ndarray, caches: dict,
 # per-slot mutable state (attention tail pages, SSM conv/state).  Only
 # ``hot`` is functionally updated here; sealing full pages into the pools
 # happens between steps, on the host, through one jitted sealer.
-
-def _common_kw(cfg, mesh, kw):
-    kw.setdefault("strum", cfg.strum)
-    kw.setdefault("accum_dtype", cfg.accum_dtype)
-    if mesh is not None:
-        # packed leaves (cfg.strum OR a schedule-built plan) need the mesh
-        # context for the sharded:* gather path; dense leaves ignore it
-        kw.setdefault("tp_mesh", mesh)
-    return kw
-
 
 def decode_step_paged(params: dict, token: jnp.ndarray, pools: dict,
                       hot: dict, cache_len: jnp.ndarray,
@@ -341,52 +314,26 @@ def decode_step_paged(params: dict, token: jnp.ndarray, pools: dict,
     Returns (logits (B, 1, V), new_hot).
     """
     kw = _common_kw(cfg, mesh, kw)
-    if token.ndim == 3:
-        x = token.astype(cfg.activation_dtype)
-    else:
-        x = embed_lookup(params["embed"], token, cfg.activation_dtype)
-    x = constrain(x, ("batch", None, None), rules)
-    p = period(cfg)
+    x = constrain(_embed(params, token, cfg), ("batch", None, None), rules)
     a_tail = active[:, None, None, None]
 
-    def group(carry, xs):
-        x = carry
-        gp, pool_g, hot_g = xs
-        new_hot = {}
-        for i in range(p):
-            bp, pool_i, hot_i = (gp[f"pos{i}"], pool_g[f"pos{i}"],
-                                 hot_g[f"pos{i}"])
-            h = apply_norm(bp["norm1"], x, cfg)
-            if "attn" in bp:
-                h, (nkt, nvt) = attn_mod.decode_attention_paged(
-                    bp["attn"], h, cfg, pool_i,
-                    (hot_i["k_tail"], hot_i["v_tail"]), spec, page_table,
-                    cache_len, cache_backend=cache_backend, **kw)
-                new_hot[f"pos{i}"] = {
-                    "k_tail": jnp.where(a_tail, nkt, hot_i["k_tail"]),
-                    "v_tail": jnp.where(a_tail, nvt, hot_i["v_tail"])}
-            else:
-                h, (ncv, nst) = mamba2.ssm_decode(
-                    bp["ssm"], h, cfg, (hot_i["conv"], hot_i["state"]), **kw)
-                new_hot[f"pos{i}"] = {
-                    "conv": jnp.where(active[:, None, None], ncv,
-                                      hot_i["conv"]),
-                    "state": jnp.where(a_tail, nst, hot_i["state"])}
-            x = x + h
-            if cfg.d_ff > 0:
-                h = apply_norm(bp["norm2"], x, cfg)
-                if "moe" in bp:
-                    h, _ = moe.moe_apply(bp["moe"], h, cfg, mesh=mesh, **kw)
-                else:
-                    h = mlp(bp["mlp"], h, cfg, **kw)
-                x = x + h
-            x = constrain(x, ("batch", None, None), rules)
-        return x, new_hot
+    def mixer(bp, h, pool, hot_i):
+        if "attn" in bp:
+            h, (nkt, nvt) = attn_mod.decode_attention_paged(
+                bp["attn"], h, cfg, pool,
+                (hot_i["k_tail"], hot_i["v_tail"]), spec, page_table,
+                cache_len, cache_backend=cache_backend, **kw)
+            return h, {"k_tail": jnp.where(a_tail, nkt, hot_i["k_tail"]),
+                       "v_tail": jnp.where(a_tail, nvt, hot_i["v_tail"])}
+        h, (ncv, nst) = mamba2.ssm_decode(
+            bp["ssm"], h, cfg, (hot_i["conv"], hot_i["state"]), **kw)
+        return h, {"conv": jnp.where(active[:, None, None], ncv,
+                                     hot_i["conv"]),
+                   "state": jnp.where(a_tail, nst, hot_i["state"])}
 
-    x, new_hot = _scan_groups(group, x, (params["blocks"], pools, hot), cfg)
-    x = apply_norm(params["final_norm"], x, cfg)
-    lg = logits(params.get("lm_head"), params["embed"], x)
-    return lg, new_hot
+    x, (new_hot, _) = _stack(params, x, cfg, mixer, (pools, hot), mesh,
+                             rules, kw)
+    return _head(params, x, cfg), new_hot
 
 
 def prefill_chunk_step(params: dict, tokens: jnp.ndarray, pools: dict,
@@ -406,68 +353,42 @@ def prefill_chunk_step(params: dict, tokens: jnp.ndarray, pools: dict,
     """
     kw = _common_kw(cfg, mesh, kw)
     ps = spec.page_size
-    if tokens.ndim == 3:
-        x = tokens.astype(cfg.activation_dtype)
-    else:
-        x = embed_lookup(params["embed"], tokens, cfg.activation_dtype)
+    x = _embed(params, tokens, cfg)
     c = x.shape[1]
-    p = period(cfg)
     # relative offset of the new tail content inside the chunk: chunk starts
     # are page-aligned, so the ragged remainder [floor(v/ps)*ps, v) is the
     # tail page; clamp keeps the slice in-bounds when the chunk is full
     # (the tail is then logically empty and masked by length anyway)
     tail_rel = jnp.clip((valid_len // ps) * ps, 0, c - ps)
 
-    def group(carry, xs):
-        x = carry
-        gp, pool_g, hot_g = xs
-        new_hot = {}
-        chunk_kv = {}
-        for i in range(p):
-            bp, pool_i, hot_i = (gp[f"pos{i}"], pool_g[f"pos{i}"],
-                                 hot_g[f"pos{i}"])
-            h = apply_norm(bp["norm1"], x, cfg)
-            if "attn" in bp:
-                h, (ck, cv) = attn_mod.prefill_attention_paged(
-                    bp["attn"], h, cfg, pool_i, spec, page_table[slot],
-                    start, cache_backend=cache_backend, **kw)
-                ck = ck.astype(hot_i["k_tail"].dtype)
-                cv = cv.astype(hot_i["v_tail"].dtype)
-                chunk_kv[f"pos{i}"] = {"k": ck, "v": cv}
-                nkv_, hd_ = ck.shape[2], ck.shape[3]
-                tk = jax.lax.dynamic_slice(ck, (0, tail_rel, 0, 0),
-                                           (1, ps, nkv_, hd_))[0]
-                tv = jax.lax.dynamic_slice(cv, (0, tail_rel, 0, 0),
-                                           (1, ps, nkv_, hd_))[0]
-                new_hot[f"pos{i}"] = {
-                    "k_tail": hot_i["k_tail"].at[slot].set(tk),
-                    "v_tail": hot_i["v_tail"].at[slot].set(tv)}
-            else:
-                h, (ncv, nst) = mamba2.ssm_prefill_chunk(
-                    bp["ssm"], h, cfg,
-                    (hot_i["conv"][slot][None], hot_i["state"][slot][None]),
-                    valid_len, **kw)
-                chunk_kv[f"pos{i}"] = {}
-                new_hot[f"pos{i}"] = {
-                    "conv": hot_i["conv"].at[slot].set(
+    def mixer(bp, h, pool, hot_i):
+        if "attn" in bp:
+            h, (ck, cv) = attn_mod.prefill_attention_paged(
+                bp["attn"], h, cfg, pool, spec, page_table[slot],
+                start, cache_backend=cache_backend, **kw)
+            ck = ck.astype(hot_i["k_tail"].dtype)
+            cv = cv.astype(hot_i["v_tail"].dtype)
+            nkv_, hd_ = ck.shape[2], ck.shape[3]
+            tk = jax.lax.dynamic_slice(ck, (0, tail_rel, 0, 0),
+                                       (1, ps, nkv_, hd_))[0]
+            tv = jax.lax.dynamic_slice(cv, (0, tail_rel, 0, 0),
+                                       (1, ps, nkv_, hd_))[0]
+            return h, ({"k_tail": hot_i["k_tail"].at[slot].set(tk),
+                        "v_tail": hot_i["v_tail"].at[slot].set(tv)},
+                       {"k": ck, "v": cv})
+        h, (ncv, nst) = mamba2.ssm_prefill_chunk(
+            bp["ssm"], h, cfg,
+            (hot_i["conv"][slot][None], hot_i["state"][slot][None]),
+            valid_len, **kw)
+        return h, ({"conv": hot_i["conv"].at[slot].set(
                         ncv[0].astype(hot_i["conv"].dtype)),
-                    "state": hot_i["state"].at[slot].set(nst[0])}
-            x = x + h
-            if cfg.d_ff > 0:
-                h = apply_norm(bp["norm2"], x, cfg)
-                if "moe" in bp:
-                    h, _ = moe.moe_apply(bp["moe"], h, cfg, mesh=mesh, **kw)
-                else:
-                    h = mlp(bp["mlp"], h, cfg, **kw)
-                x = x + h
-            x = constrain(x, ("batch", None, None), rules)
-        return x, (new_hot, chunk_kv)
+                    "state": hot_i["state"].at[slot].set(nst[0])}, {})
 
-    x, (new_hot, chunk_kv) = _scan_groups(
-        group, x, (params["blocks"], pools, hot), cfg)
-    x = apply_norm(params["final_norm"], x, cfg)
-    lg = logits(params.get("lm_head"), params["embed"], x)
-    return lg, new_hot, chunk_kv
+    x, (outs, _) = _stack(params, x, cfg, mixer, (pools, hot), mesh, rules,
+                          kw)
+    new_hot = {k: o[0] for k, o in outs.items()}
+    chunk_kv = {k: o[1] for k, o in outs.items()}
+    return _head(params, x, cfg), new_hot, chunk_kv
 
 
 def verify_chunk_step(params: dict, tokens: jnp.ndarray, pools: dict,
@@ -488,43 +409,20 @@ def verify_chunk_step(params: dict, tokens: jnp.ndarray, pools: dict,
     roll back a rejected window.  Returns ``(logits (1, C, V), chunk_kv)``.
     """
     kw = _common_kw(cfg, mesh, kw)
-    if tokens.ndim == 3:
-        x = tokens.astype(cfg.activation_dtype)
-    else:
-        x = embed_lookup(params["embed"], tokens, cfg.activation_dtype)
-    p = period(cfg)
+    x = _embed(params, tokens, cfg)
 
-    def group(carry, xs):
-        x = carry
-        gp, pool_g, hot_g = xs
-        chunk_kv = {}
-        for i in range(p):
-            bp, pool_i, hot_i = (gp[f"pos{i}"], pool_g[f"pos{i}"],
-                                 hot_g[f"pos{i}"])
-            if "attn" not in bp:
-                raise NotImplementedError(
-                    "speculative verify needs an attention-only stack: SSM "
-                    "recurrent state cannot roll back a rejected window")
-            h = apply_norm(bp["norm1"], x, cfg)
-            tails = (hot_i["k_tail"][slot][None], hot_i["v_tail"][slot][None])
-            h, (ck, cv) = attn_mod.verify_attention_paged(
-                bp["attn"], h, cfg, pool_i, tails, spec, page_table[slot],
-                start, cache_backend=cache_backend, **kw)
-            chunk_kv[f"pos{i}"] = {
-                "k": ck.astype(hot_i["k_tail"].dtype),
-                "v": cv.astype(hot_i["v_tail"].dtype)}
-            x = x + h
-            if cfg.d_ff > 0:
-                h = apply_norm(bp["norm2"], x, cfg)
-                if "moe" in bp:
-                    h, _ = moe.moe_apply(bp["moe"], h, cfg, mesh=mesh, **kw)
-                else:
-                    h = mlp(bp["mlp"], h, cfg, **kw)
-                x = x + h
-            x = constrain(x, ("batch", None, None), rules)
-        return x, chunk_kv
+    def mixer(bp, h, pool, hot_i):
+        if "attn" not in bp:
+            raise NotImplementedError(
+                "speculative verify needs an attention-only stack: SSM "
+                "recurrent state cannot roll back a rejected window")
+        tails = (hot_i["k_tail"][slot][None], hot_i["v_tail"][slot][None])
+        h, (ck, cv) = attn_mod.verify_attention_paged(
+            bp["attn"], h, cfg, pool, tails, spec, page_table[slot],
+            start, cache_backend=cache_backend, **kw)
+        return h, {"k": ck.astype(hot_i["k_tail"].dtype),
+                   "v": cv.astype(hot_i["v_tail"].dtype)}
 
-    x, chunk_kv = _scan_groups(group, x, (params["blocks"], pools, hot), cfg)
-    x = apply_norm(params["final_norm"], x, cfg)
-    lg = logits(params.get("lm_head"), params["embed"], x)
-    return lg, chunk_kv
+    x, (chunk_kv, _) = _stack(params, x, cfg, mixer, (pools, hot), mesh,
+                              rules, kw)
+    return _head(params, x, cfg), chunk_kv

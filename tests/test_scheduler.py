@@ -15,11 +15,15 @@ from repro.models.params import init_params
 from repro.serving import BatchScheduler, Request
 
 
-@pytest.fixture(scope="module")
-def setup():
-    cfg = dataclasses.replace(get_smoke_config("qwen2_7b"), dtype="float32")
+def _model(arch):
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
     params = init_params(model_defs(cfg), seed=0, dtype_override="float32")
     return cfg, params
+
+
+@pytest.fixture(scope="module")
+def setup():
+    return _model("qwen2_7b")
 
 
 def _reference_tokens(cfg, params, prompt, n):
@@ -27,7 +31,11 @@ def _reference_tokens(cfg, params, prompt, n):
     return [int(t) for t in toks[0]]
 
 
-def test_batched_matches_sequential(setup):
+# one family per block structure the lanes share: GQA with QKV bias, MHA
+# with a non-parametric norm, MoE, SSM, and the SSM + attention + MoE hybrid
+@pytest.mark.parametrize("arch", ["qwen2_7b", "olmo_1b", "qwen3_moe_235b_a22b",
+                                  "mamba2_780m", "jamba_1_5_large_398b"])
+def test_batched_matches_sequential(arch):
     """Interleaved slot decoding == one-at-a-time serving, per request.
 
     ``prefill="serial"`` pins the monolithic prefill executable (identical
@@ -36,7 +44,7 @@ def test_batched_matches_sequential(setup):
     compared teacher-forced in tests/test_serving_runtime.py (its online
     prefill attention is a different float reduction).
     """
-    cfg, params = setup
+    cfg, params = _model(arch)
     rng = np.random.default_rng(0)
     prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, size=(8 + i,)),
                            jnp.int32) for i in range(3)]
