@@ -33,7 +33,9 @@ def _draft_mode_of(variant_name: str) -> str:
     return tail[4:] if tail.startswith("xla_") else tail
 
 
-def _note_dispatch(variant, wleaf: dict, *, sharded: bool = False) -> None:
+def _note_dispatch(variant, wleaf: dict, *, sharded: bool = False,
+                   packed: Optional[packing.PackedStruM] = None,
+                   x: Optional[jnp.ndarray] = None) -> None:
     """Count one dispatch through ``variant`` into the active recorders.
 
     Dispatch runs at jit-trace time, so per-executable these counters fire
@@ -45,7 +47,9 @@ def _note_dispatch(variant, wleaf: dict, *, sharded: bool = False) -> None:
     twin of :func:`repro.telemetry.all_gather_stats`).  ``draft:*`` calls
     stream only their mode's field subset, counted as such and mirrored
     under ``spec/draft_packed_bytes`` (the speculative draft lane's weight
-    read).
+    read).  ``dispatch/grid_steps`` counts the grid steps of each call
+    over ``x`` (M, K) rows of ``packed`` whose variant registered
+    ``grid_steps`` (the 2-D Pallas and draft lowerings).
     """
     if not telemetry.enabled():
         return
@@ -59,6 +63,9 @@ def _note_dispatch(variant, wleaf: dict, *, sharded: bool = False) -> None:
         payload = sum(int(wleaf[k].size) for k in ("mask", "hi", "lo")
                       if k in wleaf)
     telemetry.inc("dispatch/packed_bytes", payload)
+    if variant.grid_steps is not None and x is not None:
+        telemetry.inc("dispatch/grid_steps", variant.grid_steps(
+            x.shape[0], packed, x_bytes=x.dtype.itemsize))
     if sharded:
         telemetry.inc("dispatch/sharded/gathered_packed_bytes", payload)
 
@@ -211,11 +218,12 @@ def dispatch(wleaf: dict, x: jnp.ndarray, *,
     info = LeafInfo(k_dim=k_dim, n_out=wleaf["scale"].shape[-1],
                     lead=(), name="")
     variant, interpret = _pick(cfg, info, spec, backend)
-    _note_dispatch(variant, wleaf)
     packed = _as_packed(wleaf, cfg, k_dim)
     lead = x.shape[:-1]
+    x2 = x.reshape(-1, k_dim)
+    _note_dispatch(variant, wleaf, packed=packed, x=x2)
     with jax.named_scope(variant.name):
-        y = variant.fn(x.reshape(-1, k_dim), packed, out_dtype=out_dtype,
+        y = variant.fn(x2, packed, out_dtype=out_dtype,
                        interpret=interpret, accum_dtype=accum_dtype)
     return y.reshape(lead + (y.shape[-1],))
 

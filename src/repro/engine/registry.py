@@ -113,6 +113,10 @@ class KernelVariant:
     mode, so full-fidelity and draft lowerings never compete — a draft
     variant's ``supports`` should additionally match ``info.draft`` so the
     modes don't compete with each other.
+
+    ``grid_steps(m, packed, x_bytes)``, where set, gives the grid steps of
+    one call over ``m`` rows (the trace-time ``dispatch/grid_steps``
+    counter).
     """
 
     name: str
@@ -127,6 +131,7 @@ class KernelVariant:
     cache: bool = False
     attn: bool = False
     draft: bool = False
+    grid_steps: Optional[Callable] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,7 +186,8 @@ def register_kernel(name: str, *, supports: Callable, family: str = "pallas",
                     priority: int = 0, description: str = "",
                     grouped: bool = False, sharded: bool = False,
                     redispatch: bool = False, cache: bool = False,
-                    attn: bool = False, draft: bool = False):
+                    attn: bool = False, draft: bool = False,
+                    grid_steps: Optional[Callable] = None):
     """Decorator: register ``fn`` as kernel variant ``name``.
 
     Re-registering a name replaces the previous entry (latest wins), so a
@@ -198,7 +204,7 @@ def register_kernel(name: str, *, supports: Callable, family: str = "pallas",
             name=name, fn=fn, supports=supports, family=family,
             priority=priority, description=description, grouped=grouped,
             sharded=sharded, redispatch=redispatch, cache=cache, attn=attn,
-            draft=draft)
+            draft=draft, grid_steps=grid_steps)
         return fn
     return deco
 

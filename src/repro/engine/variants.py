@@ -22,6 +22,8 @@ still fall back to ``xla:dequant``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 
 from repro.core import packing
@@ -37,10 +39,17 @@ def _stacked(cfg, info):
     return bool(info.lead)
 
 
+def _steps(variant):
+    """``grid_steps`` of a 2-D lowering or draft mode: its call's grid as
+    :func:`ops.grid_plan` lays it out."""
+    return functools.partial(ops.grid_steps, variant=variant)
+
+
 @register_kernel(
     "pallas:onehot", family="pallas", priority=10,
     supports=lambda cfg, info: _two_d(cfg, info) and cfg.w % 8 == 0,
-    description="general in-VMEM decode: mask unpack + one-hot scatter")
+    description="general in-VMEM decode: mask unpack + one-hot scatter",
+    grid_steps=_steps("onehot"))
 def _onehot(x2, packed, *, out_dtype=None, interpret=None, accum_dtype=None):
     return ops.strum_matmul(x2, packed, out_dtype=out_dtype,
                             interpret=interpret, variant="onehot")
@@ -50,7 +59,8 @@ def _onehot(x2, packed, *, out_dtype=None, interpret=None, accum_dtype=None):
     "pallas:maskfree", family="pallas", priority=20,
     supports=lambda cfg, info: (_two_d(cfg, info) and cfg.n_low == cfg.w
                                 and cfg.method in ("dliq", "mip2q")),
-    description="p=1.0: decode lo fields in order, no mask/hi stream")
+    description="p=1.0: decode lo fields in order, no mask/hi stream",
+    grid_steps=_steps("maskfree"))
 def _maskfree(x2, packed, *, out_dtype=None, interpret=None, accum_dtype=None):
     return ops.strum_matmul(x2, packed, out_dtype=out_dtype,
                             interpret=interpret, variant="maskfree")
@@ -59,7 +69,8 @@ def _maskfree(x2, packed, *, out_dtype=None, interpret=None, accum_dtype=None):
 @register_kernel(
     "pallas:dense", family="pallas", priority=20,
     supports=lambda cfg, info: _two_d(cfg, info) and cfg.n_low == 0,
-    description="n_low=0: hi payload is the block in order; reshape + scale")
+    description="n_low=0: hi payload is the block in order; reshape + scale",
+    grid_steps=_steps("dense"))
 def _dense(x2, packed, *, out_dtype=None, interpret=None, accum_dtype=None):
     return ops.strum_matmul(x2, packed, out_dtype=out_dtype,
                             interpret=interpret, variant="dense")
@@ -116,7 +127,8 @@ def _draft_mode(mode):
     "draft:histream", family="pallas", priority=10, draft=True,
     supports=lambda cfg, info: _draft_mode("histream")(cfg, info)
     and cfg.w % 8 == 0,
-    description="draft: mask+hi stream only, lo decodes to zero")
+    description="draft: mask+hi stream only, lo decodes to zero",
+    grid_steps=_steps("histream"))
 def _draft_histream(x2, packed, *, out_dtype=None, interpret=None,
                     accum_dtype=None):
     return ops.strum_matmul_draft(x2, packed, mode="histream",
@@ -126,7 +138,8 @@ def _draft_histream(x2, packed, *, out_dtype=None, interpret=None,
 @register_kernel(
     "draft:maskfree_p", family="pallas", priority=10, draft=True,
     supports=_draft_mode("maskfree_p"),
-    description="draft: hi stream only, block treated as all-high")
+    description="draft: hi stream only, block treated as all-high",
+    grid_steps=_steps("maskfree_p"))
 def _draft_maskfree_p(x2, packed, *, out_dtype=None, interpret=None,
                       accum_dtype=None):
     return ops.strum_matmul_draft(x2, packed, mode="maskfree_p",

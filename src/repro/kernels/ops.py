@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.packing import PackedStruM
-from repro.kernels.strum_matmul import (strum_matmul_pallas,
+from repro.kernels.strum_matmul import (SUB_K, SUB_M, SUB_N, block_shape,
+                                        strum_matmul_pallas,
                                         strum_matmul_pallas_dense,
                                         strum_matmul_pallas_grouped,
                                         strum_matmul_pallas_grouped_dense,
@@ -34,6 +35,7 @@ from repro.kernels.strum_matmul import (strum_matmul_pallas,
                                         strum_matmul_pallas_maskfree_p)
 
 __all__ = ["strum_matmul", "strum_gemv", "strum_grouped_matmul",
+           "grid_plan", "grid_steps",
            "strum_matmul_draft", "strum_gemv_draft", "draft_field_set",
            "default_interpret", "PALLAS_VARIANTS", "DRAFT_MODES"]
 
@@ -105,12 +107,54 @@ def _pick_block(dim: int, pref: int, align: int) -> int:
     return max(align, min((pref // align) * align, padded))
 
 
-def _prepare(x: jnp.ndarray, packed: PackedStruM, block_m: int, block_n: int,
-             block_k: int):
-    """Flatten leading dims, pad every operand to block multiples.
+#: the packed fields each 2-D lowering streams
+_VARIANT_FIELDS = {"onehot": ("mask", "hi", "lo"), "maskfree": ("lo",),
+                   "dense": ("hi",)}
+
+
+def _field_rows(variant: str, packed: PackedStruM) -> tuple:
+    """Per-block rows of each field ``variant`` (a 2-D lowering or a draft
+    mode) streams; empty fields get the one zero row :func:`_min1` gives
+    them."""
+    fields = _VARIANT_FIELDS.get(variant) or draft_field_set(variant)
+    return tuple(max(getattr(packed, f).shape[1], 1) for f in fields)
+
+
+def grid_plan(m: int, k_pad: int, n: int, w: int, field_rows, *,
+              x_bytes: int = 2):
+    """Tiling of a 2-D packed call: ``(bm, sub_k, sub_n, BK, BN, grid)``.
+
+    ``(bm, sub_k, sub_n)`` is the sub-tile one decode and dot cover
+    (``SUB_M x SUB_K x SUB_N`` clamped to the axes by :func:`_pick_block`);
+    every axis is padded to it, and
+    :func:`~repro.kernels.strum_matmul.block_shape` groups sub-tiles into
+    the ``(BK, BN)`` block a grid step streams.
+    """
+    bm = _pick_block(m, SUB_M, 8)
+    sn = _pick_block(n, SUB_N, 128)
+    sk = _pick_block(k_pad, SUB_K, w)
+    m_pad, n_pad, k_pad = (-(-d // b) * b for d, b in
+                           ((m, bm), (n, sn), (k_pad, sk)))
+    bk, bn = block_shape(bm, k_pad, n_pad, w, field_rows, sub_k=sk,
+                         sub_n=sn, x_bytes=x_bytes)
+    return bm, sk, sn, bk, bn, (m_pad // bm, n_pad // bn, k_pad // bk)
+
+
+def grid_steps(m: int, packed: PackedStruM, variant: str = "onehot",
+               x_bytes: int = 2) -> int:
+    """Grid steps of one call of ``variant`` (a 2-D lowering of
+    :func:`strum_matmul` or a draft mode) over ``m`` rows of ``packed``."""
+    *_, grid = grid_plan(m, packed.mask.shape[0] * packed.w, packed.n_out,
+                         packed.w, _field_rows(variant, packed),
+                         x_bytes=x_bytes)
+    return math.prod(grid)
+
+
+def _prepare(x: jnp.ndarray, packed: PackedStruM, variant: str):
+    """Flatten leading dims, pad every operand to sub-tile multiples.
 
     Returns ``(x2, mask, hi, lo, scale, dims)`` where ``dims`` carries the
-    block sizes and the unpadded (m, n) for the final slice.
+    tiling of :func:`grid_plan` and the unpadded (m, n) for the final slice.
     """
     lead = x.shape[:-1]
     k_in = x.shape[-1]
@@ -123,50 +167,50 @@ def _prepare(x: jnp.ndarray, packed: PackedStruM, block_m: int, block_n: int,
     k_pad = packed.mask.shape[0] * w               # padded K (block multiple)
     x2 = _pad_axis(x2, 1, k_pad) if k_pad != k_in else x2
 
-    bm = _pick_block(m, block_m, 8)
-    bn = _pick_block(n, block_n, 128)
-    bk = _pick_block(k_pad, block_k, w)
+    bm, sk, sn, bk, bn, _ = grid_plan(
+        m, k_pad, n, w, _field_rows(variant, packed),
+        x_bytes=x2.dtype.itemsize)
 
-    x2 = _pad_axis(_pad_axis(x2, 0, bm), 1, bk)
+    x2 = _pad_axis(_pad_axis(x2, 0, bm), 1, sk)
 
-    mask = _pad_axis(_pad_axis(packed.mask, 0, bk // w), 2, bn)
-    hi = _pad_axis(_pad_axis(_min1(packed.hi, 1), 0, bk // w), 2, bn)
-    lo = _pad_axis(_pad_axis(_min1(packed.lo, 1), 0, bk // w), 2, bn)
+    mask = _pad_axis(_pad_axis(packed.mask, 0, sk // w), 2, sn)
+    hi = _pad_axis(_pad_axis(_min1(packed.hi, 1), 0, sk // w), 2, sn)
+    lo = _pad_axis(_pad_axis(_min1(packed.lo, 1), 0, sk // w), 2, sn)
     # zero scale in padded columns kills any junk the decoder would produce
-    scale = _pad_axis(packed.scale, 1, bn)
-    return x2, mask, hi, lo, scale, (lead, m, n, bm, bn, bk)
+    scale = _pad_axis(packed.scale, 1, sn)
+    return x2, mask, hi, lo, scale, (lead, m, n, bm, sk, sn, bk, bn)
 
 
 def strum_matmul(x: jnp.ndarray, packed: PackedStruM, *,
-                 out_dtype=None, block_m: int = 128, block_n: int = 256,
-                 block_k: int = 256, interpret: bool | None = None,
+                 out_dtype=None, interpret: bool | None = None,
                  variant: str = "onehot") -> jnp.ndarray:
     """y = x @ dequant(packed), streaming compressed weights.
 
     x: (..., K) — leading dims are flattened into M.
     Returns (..., N) in ``out_dtype`` (default: x.dtype).
+    Each grid step streams the block :func:`grid_plan` chooses from the
+    shapes and decodes it in sub-tiles.
     """
     if interpret is None:
         interpret = default_interpret()
     out_dtype = out_dtype or x.dtype
     _validate_variant(variant, packed)
-    x2, mask, hi, lo, scale, (lead, m, n, bm, bn, bk) = _prepare(
-        x, packed, block_m, block_n, block_k)
+    x2, mask, hi, lo, scale, (lead, m, n, bm, sk, sn, bk, bn) = _prepare(
+        x, packed, variant)
     w = packed.w
+    tiles = dict(block_m=bm, block_n=bn, block_k=bk, sub_n=sn, sub_k=sk,
+                 interpret=interpret)
 
     if variant == "onehot":
         y = strum_matmul_pallas(
             x2, mask, hi, lo, scale,
             w=w, n_low=packed.n_low, q=packed.q, method=packed.method,
-            block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+            **tiles)
     elif variant == "maskfree":
         y = strum_matmul_pallas_maskfree(
-            x2, lo, scale, w=w, q=packed.q, method=packed.method,
-            block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+            x2, lo, scale, w=w, q=packed.q, method=packed.method, **tiles)
     else:
-        y = strum_matmul_pallas_dense(
-            x2, hi, scale, w=w,
-            block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+        y = strum_matmul_pallas_dense(x2, hi, scale, w=w, **tiles)
     return y[:m, :n].reshape(lead + (n,)).astype(out_dtype)
 
 
@@ -181,8 +225,7 @@ def draft_field_set(mode: str) -> tuple:
 
 
 def strum_matmul_draft(x: jnp.ndarray, packed: PackedStruM, *, mode: str,
-                       out_dtype=None, block_m: int = 128, block_n: int = 256,
-                       block_k: int = 256,
+                       out_dtype=None,
                        interpret: bool | None = None) -> jnp.ndarray:
     """Reduced-fidelity y = x @ draft_dequant(packed), same payload buffers.
 
@@ -211,25 +254,25 @@ def strum_matmul_draft(x: jnp.ndarray, packed: PackedStruM, *, mode: str,
 
     k_pad = packed.hi.shape[0] * w                 # padded K (block multiple)
     x2 = _pad_axis(x2, 1, k_pad) if k_pad != k_in else x2
-    bm = _pick_block(m, block_m, 8)
-    bn = _pick_block(n, block_n, 128)
-    bk = _pick_block(k_pad, block_k, w)
-    x2 = _pad_axis(_pad_axis(x2, 0, bm), 1, bk)
+    bm, sk, sn, bk, bn, _ = grid_plan(m, k_pad, n, w,
+                                      _field_rows(mode, packed),
+                                      x_bytes=x2.dtype.itemsize)
+    x2 = _pad_axis(_pad_axis(x2, 0, bm), 1, sk)
 
-    hi = _pad_axis(_pad_axis(packed.hi, 0, bk // w), 2, bn)
-    scale = _pad_axis(packed.scale, 1, bn)
+    hi = _pad_axis(_pad_axis(packed.hi, 0, sk // w), 2, sn)
+    scale = _pad_axis(packed.scale, 1, sn)
+    tiles = dict(block_m=bm, block_n=bn, block_k=bk, sub_n=sn, sub_k=sk,
+                 interpret=interpret)
     if mode == "histream":
         if w % 8:
             raise ValueError(f"histream draft needs byte-aligned mask rows "
                              f"(w={w})")
-        mask = _pad_axis(_pad_axis(packed.mask, 0, bk // w), 2, bn)
+        mask = _pad_axis(_pad_axis(packed.mask, 0, sk // w), 2, sn)
         y = strum_matmul_pallas_histream(
-            x2, mask, hi, scale, w=w, n_low=packed.n_low,
-            block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+            x2, mask, hi, scale, w=w, n_low=packed.n_low, **tiles)
     else:
         y = strum_matmul_pallas_maskfree_p(
-            x2, hi, scale, w=w, n_low=packed.n_low,
-            block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+            x2, hi, scale, w=w, n_low=packed.n_low, **tiles)
     return y[:m, :n].reshape(lead + (n,)).astype(out_dtype)
 
 
@@ -239,7 +282,6 @@ def strum_gemv_draft(x: jnp.ndarray, packed: PackedStruM, *, mode: str,
     """Decode-path draft matvec: the fidelity knob where it pays — the op is
     HBM-bound, so the skipped streams' bytes convert 1:1 into latency."""
     return strum_matmul_draft(x, packed, mode=mode, out_dtype=out_dtype,
-                              block_m=8, block_n=512, block_k=512,
                               interpret=interpret)
 
 
@@ -322,6 +364,5 @@ def strum_gemv(x: jnp.ndarray, packed: PackedStruM, *, out_dtype=None,
     This is where StruM's bandwidth ratio converts 1:1 into decode latency —
     the op is HBM-bound, so bytes saved = time saved (DESIGN.md §2).
     """
-    return strum_matmul(x, packed, out_dtype=out_dtype, block_m=8,
-                        block_n=512, block_k=512, interpret=interpret,
+    return strum_matmul(x, packed, out_dtype=out_dtype, interpret=interpret,
                         variant=variant)

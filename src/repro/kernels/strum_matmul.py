@@ -216,60 +216,156 @@ def _decode_blocks_maskfree(lo_u8, *, w, q, method):
     return _place(rows, lax.broadcasted_iota(jnp.int32, (bnb, w, bn), 1))
 
 
-def _kernel(x_ref, mask_ref, hi_ref, lo_ref, scale_ref, o_ref, *,
-            w, n_low, q, method):
-    k = pl.program_id(2)
+# ------------------------------------------------------------------- 2-D --
+#
+# Grid (M/bm, N/BN, K/BK).  A grid step streams one (BK, BN) block of each
+# packed field and folds it into the resident (bm, BN) f32 output in
+# (sub_k, sub_n) sub-tiles, each decoded and multiplied as one tile.  A
+# step costs a fixed ~0.07 us on v5e whatever it holds, so the block is
+# chosen large (:func:`block_shape`), while the sub-tile keeps the decode's
+# working set and Mosaic's unrolled code at one tile's size.  Each output
+# column sums its K sub-tiles in ascending order, one f32 dot at a time:
+# the order of a grid over (sub_k, sub_n) tiles, so the results are
+# bit-equal to it.
 
-    @pl.when(k == 0)
+#: the tile one decode and f32 dot cover, rows x K x N (clamped to small
+#: axes by ``ops.grid_plan``); a grid step's block is a whole number of them
+SUB_M, SUB_K, SUB_N = 128, 256, 256
+#: packed weights a grid step aims for (0.92 MB of Eq.-1 bytes at p = 0.5)
+STEP_WEIGHTS = 1 << 20
+#: bound on :func:`_vmem_bytes`; the compiler is given ``VMEM_LIMIT``
+#: (v5e has 128 MiB of VMEM per core)
+VMEM_BUDGET = 24 << 20
+VMEM_LIMIT = 48 << 20
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _vmem_bytes(bm, bk, bn, w, field_rows, x_bytes, sub_k, sub_n) -> int:
+    """Estimated VMEM of one grid step: the double-buffered x, payload,
+    scale and output blocks (8-bit rows tile by 4 sublanes, the x block's
+    rows by 8 times its packing, a 1-row scale by 8) and a dozen f32
+    sub-tiles of decode temporaries."""
+    payload = bk // w * sum(_round_up(r, 4) for r in field_rows) * bn
+    x = _round_up(bm, 8 * (4 // x_bytes)) * bk * x_bytes
+    blocks = payload + x + 8 * bn * 4 + _round_up(bm, 8) * bn * 4
+    return 2 * blocks + 12 * sub_k * sub_n * 4
+
+
+def _divisor_multiples(dim: int, step: int):
+    """Multiples of ``step`` that divide ``dim`` (itself a multiple)."""
+    return [step * d for d in range(1, dim // step + 1)
+            if (dim // step) % d == 0]
+
+
+def block_shape(bm: int, k_pad: int, n_pad: int, w: int, field_rows, *,
+                sub_k: int, sub_n: int, x_bytes: int = 2) -> tuple[int, int]:
+    """``(BK, BN)``: the packed block one grid step streams, from shapes alone.
+
+    ``k_pad`` and ``n_pad`` are the padded axes, multiples of the sub-tile
+    ``(sub_k, sub_n)``; ``field_rows`` the per-block rows of each streamed
+    field.  Among sub-tile multiples dividing both axes (no padding beyond
+    the sub-tile's), whose :func:`_vmem_bytes` fits ``VMEM_BUDGET``: the most
+    weights per step up to ``STEP_WEIGHTS``, then the fewest (more steps
+    for the double buffer to overlap), then the longest K.
+    """
+    field_rows = tuple(field_rows)
+    best, best_key = (sub_k, sub_n), None
+    for bk in _divisor_multiples(k_pad, sub_k):
+        for bn in _divisor_multiples(n_pad, sub_n):
+            if _vmem_bytes(bm, bk, bn, w, field_rows, x_bytes, sub_k,
+                           sub_n) > VMEM_BUDGET:
+                continue
+            work = bk * bn
+            key = (min(work, STEP_WEIGHTS), -work, bk)
+            if best_key is None or key > best_key:
+                best, best_key = (bk, bn), key
+    return best
+
+
+def _fold_kernel(x_ref, *refs, decode, w, sub_k, sub_n):
+    """o += x @ W over one grid step's block, in (sub_k, sub_n) sub-tiles.
+
+    ``refs`` are the packed field blocks ``(BK/w, rows, BN)``, the scale
+    block ``(1, BN)`` and the output block ``(bm, BN)``; ``decode(*fields,
+    scale)`` turns one sub-tile's field slices into its scaled
+    ``(sub_k, sub_n)`` f32 weights.
+    """
+    *field_refs, scale_ref, o_ref = refs
+
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    wv = _decode_tile(mask_ref[...], hi_ref[...], lo_ref[...], scale_ref[...],
-                      w=w, n_low=n_low, q=q, method=method)
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
+    sbnb = sub_k // w
+    n_k = x_ref.shape[1] // sub_k
+
+    def column(j, carry):
+        cols = pl.ds(pl.multiple_of(j * sub_n, sub_n), sub_n)
+
+        def k_tile(i, acc):
+            blocks = pl.ds(pl.multiple_of(i * sbnb, sbnb), sbnb)
+            wv = decode(*(r[blocks, :, cols] for r in field_refs),
+                        scale_ref[:, cols])
+            xv = x_ref[:, pl.ds(pl.multiple_of(i * sub_k, sub_k), sub_k)]
+            return acc + jnp.dot(xv.astype(jnp.float32), wv,
+                                 preferred_element_type=jnp.float32)
+
+        o_ref[:, cols] = lax.fori_loop(0, n_k, k_tile, o_ref[:, cols])
+        return carry
+
+    lax.fori_loop(0, o_ref.shape[1] // sub_n, column, 0)
+
+
+def _matmul_call(decode, x, fields, scale, *, w, block_m, block_n, block_k,
+                 sub_k, sub_n, interpret):
+    """pallas_call shared by the 2-D kernels: y(M, N) = x @ W, W streamed as
+    ``fields`` ((nb, rows, N) each) and ``scale`` (1, N); every axis
+    pre-padded to its block."""
+    m, k_dim = x.shape
+    nb, _, n = fields[0].shape
+    sub_k, sub_n = sub_k or block_k, sub_n or block_n
+    assert k_dim == nb * w, (k_dim, nb, w)
+    assert sub_k % w == 0 and block_k % sub_k == 0 and block_n % sub_n == 0
+    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
+    bnb = block_k // w
+    field_spec = lambda rows: pl.BlockSpec(                    # noqa: E731
+        (bnb, rows, block_n), lambda i, j, kk: (kk, 0, j))
+    return pl.pallas_call(
+        functools.partial(_fold_kernel, decode=decode, w=w, sub_k=sub_k,
+                          sub_n=sub_n),
+        grid=(m // block_m, n // block_n, k_dim // block_k),
+        in_specs=[pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
+                  *(field_spec(f.shape[1]) for f in fields),
+                  pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j))],
+        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        interpret=interpret,
+        compiler_params=_mosaic_params(interpret,
+                                       vmem_limit_bytes=VMEM_LIMIT),
+    )(x, *fields, scale)
 
 
 @_scoped("strum:onehot")
 def strum_matmul_pallas(x, mask, hi, lo, scale, *, w: int, n_low: int, q: int,
                         method: str, block_m: int = 128, block_n: int = 128,
-                        block_k: int = 128, interpret: bool = True):
-    """y(M,N) = x(M,K) @ dequant(packed W).  All dims pre-padded to tiles.
+                        block_k: int = 128, sub_n: int | None = None,
+                        sub_k: int | None = None, interpret: bool = True):
+    """y(M,N) = x(M,K) @ dequant(packed W).  All dims pre-padded to blocks.
 
     Operands are the PackedStruM fields:
       mask  (nb, w//8, N) uint8,  hi (nb, n_high, N) int8,
       lo    (nb, lb, N)   uint8,  scale (1, N) f32.
+    A grid step streams a ``(block_k, block_n)`` block and decodes it in
+    ``(sub_k, sub_n)`` sub-tiles (default: the whole block).
     """
-    m, k_dim = x.shape
-    nb = mask.shape[0]
-    n = mask.shape[2]
-    assert k_dim == nb * w, (k_dim, nb, w)
     assert w % 8 == 0, "kernel path requires byte-aligned mask rows"
-    assert block_k % w == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    bnb = block_k // w
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-
-    kern = functools.partial(_kernel, w=w, n_low=n_low, q=q, method=method)
-    n_high = w - n_low
-    lb = lo.shape[1]
-    mb = w // 8
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bnb, mb, block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((bnb, max(n_high, 1), block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((bnb, max(lb, 1), block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-        compiler_params=_mosaic_params(interpret),
-    )(x, mask, hi, lo, scale)
-    return out
+    return _matmul_call(
+        functools.partial(_decode_tile, w=w, n_low=n_low, q=q, method=method),
+        x, [mask, hi, lo], scale, w=w, block_m=block_m, block_n=block_n,
+        block_k=block_k, sub_k=sub_k, sub_n=sub_n, interpret=interpret)
 
 
 def _mosaic_params(interpret: bool, grid_rank: int = 3,
@@ -282,94 +378,42 @@ def _mosaic_params(interpret: bool, grid_rank: int = 3,
         vmem_limit_bytes=vmem_limit_bytes)
 
 
-def _kernel_maskfree(x_ref, lo_ref, scale_ref, o_ref, *, w, q, method):
-    """p = 1.0 decode: lo payload is the whole block, already in order."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    wv = _decode_tile_maskfree(lo_ref[...], scale_ref[...], w=w, q=q,
-                               method=method)
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
-
-
 @_scoped("strum:maskfree")
 def strum_matmul_pallas_maskfree(x, lo, scale, *, w: int, q: int, method: str,
                                  block_m: int = 128, block_n: int = 128,
-                                 block_k: int = 128, interpret: bool = True):
+                                 block_k: int = 128, sub_n: int | None = None,
+                                 sub_k: int | None = None,
+                                 interpret: bool = True):
     """y = x @ dequant(W) when n_low == w: mask and hi are never streamed."""
-    m, k_dim = x.shape
-    nb, lb, n = lo.shape
-    assert k_dim == nb * w, (k_dim, nb, w)
     assert method in ("dliq", "mip2q"), method
-    assert block_k % w == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    bnb = block_k // w
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-    kern = functools.partial(_kernel_maskfree, w=w, q=q, method=method)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bnb, lb, block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-        compiler_params=_mosaic_params(interpret),
-    )(x, lo, scale)
+    return _matmul_call(
+        functools.partial(_decode_tile_maskfree, w=w, q=q, method=method),
+        x, [lo], scale, w=w, block_m=block_m, block_n=block_n,
+        block_k=block_k, sub_k=sub_k, sub_n=sub_n, interpret=interpret)
 
 
-def _kernel_dense(x_ref, hi_ref, scale_ref, o_ref, *, w):
-    """n_low = 0 decode: hi payload is the block in order; reshape + scale."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    hv = hi_ref[...].astype(jnp.float32)                     # (bnb, w, bn)
-    bnb, _, bn = hv.shape
-    wv = hv.reshape(bnb * w, bn) * scale_ref[...]
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
+def _decode_dense(hi_i8, scale_f32):
+    """n_low = 0 decode: the hi payload is the block in order."""
+    hv = hi_i8.astype(jnp.float32)                           # (bnb, w, bn)
+    bnb, w, bn = hv.shape
+    return hv.reshape(bnb * w, bn) * scale_f32
 
 
 @_scoped("strum:dense")
 def strum_matmul_pallas_dense(x, hi, scale, *, w: int,
                               block_m: int = 128, block_n: int = 128,
-                              block_k: int = 128, interpret: bool = True):
+                              block_k: int = 128, sub_n: int | None = None,
+                              sub_k: int | None = None,
+                              interpret: bool = True):
     """y = x @ dequant(W) when n_low == 0: pure-INT8 blocks, no mask/lo.
 
     The only variant with no ``w % 8`` constraint — the hi payload carries
     all ``w`` values per block, so the mask header is never consulted.
     """
-    m, k_dim = x.shape
-    nb, rows, n = hi.shape
-    assert rows == w and k_dim == nb * w, (rows, w, k_dim, nb)
-    assert block_k % w == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    bnb = block_k // w
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-    kern = functools.partial(_kernel_dense, w=w)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bnb, w, block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-        compiler_params=_mosaic_params(interpret),
-    )(x, hi, scale)
+    assert hi.shape[1] == w, (hi.shape, w)
+    return _matmul_call(
+        _decode_dense, x, [hi], scale, w=w, block_m=block_m, block_n=block_n,
+        block_k=block_k, sub_k=sub_k, sub_n=sub_n, interpret=interpret)
 
 
 # ----------------------------------------------------------------- draft --
@@ -390,80 +434,36 @@ def strum_matmul_pallas_dense(x, hi, scale, *, w: int,
 #                                    leading positions — position-scrambled
 #                                    and lossier, but mask- and lo-free.
 
-def _kernel_histream(x_ref, mask_ref, hi_ref, scale_ref, o_ref, *, w, n_low):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # the sparsity decode: high values placed, every low position zero
-    wv = _decode_tile(mask_ref[...], hi_ref[...], None, scale_ref[...],
-                      w=w, n_low=n_low, q=0, method="sparsity")
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
-
-
 @_scoped("strum:draft_histream")
 def strum_matmul_pallas_histream(x, mask, hi, scale, *, w: int, n_low: int,
                                  block_m: int = 128, block_n: int = 128,
-                                 block_k: int = 128, interpret: bool = True):
+                                 block_k: int = 128, sub_n: int | None = None,
+                                 sub_k: int | None = None,
+                                 interpret: bool = True):
     """Draft decode: hi codes at their masked positions, lo set to zero.
 
     Streams mask + hi + scale — the lo payload never appears as an
     operand, so the draft step's HBM read is the Eq.-1 payload minus the
     ``ceil(n_low*q/8)`` bytes/block of the lo stream.
     """
-    m, k_dim = x.shape
-    nb = mask.shape[0]
-    n = mask.shape[2]
-    assert k_dim == nb * w, (k_dim, nb, w)
     assert w % 8 == 0, "histream path requires byte-aligned mask rows"
-    assert block_k % w == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    bnb = block_k // w
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-    kern = functools.partial(_kernel_histream, w=w, n_low=n_low)
-    n_high = w - n_low
-    mb = w // 8
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bnb, mb, block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((bnb, max(n_high, 1), block_n),
-                         lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-        compiler_params=_mosaic_params(interpret),
-    )(x, mask, hi, scale)
 
-
-def _kernel_maskfree_p(x_ref, hi_ref, scale_ref, o_ref, *, w, n_high):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    hv = hi_ref[...].astype(jnp.float32)                     # (bnb, n_high, bn)
-    bnb, _, bn = hv.shape
-    if n_high < w:
-        hv = jnp.concatenate(
-            [hv, jnp.zeros((bnb, w - n_high, bn), jnp.float32)], axis=1)
-    wv = hv.reshape(bnb * w, bn) * scale_ref[...]
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, wv, preferred_element_type=jnp.float32)
+    def decode(mask_u8, hi_i8, scale_f32):
+        # the sparsity decode: high values placed, every low position zero
+        return _decode_tile(mask_u8, hi_i8, None, scale_f32, w=w,
+                            n_low=n_low, q=0, method="sparsity")
+    return _matmul_call(
+        decode, x, [mask, hi], scale, w=w, block_m=block_m, block_n=block_n,
+        block_k=block_k, sub_k=sub_k, sub_n=sub_n, interpret=interpret)
 
 
 @_scoped("strum:draft_maskfree_p")
 def strum_matmul_pallas_maskfree_p(x, hi, scale, *, w: int, n_low: int,
                                    block_m: int = 128, block_n: int = 128,
-                                   block_k: int = 128, interpret: bool = True):
+                                   block_k: int = 128,
+                                   sub_n: int | None = None,
+                                   sub_k: int | None = None,
+                                   interpret: bool = True):
     """Draft decode: hi codes at the leading block positions, rest zero.
 
     Streams hi + scale only — neither the mask header nor the lo payload is
@@ -471,30 +471,20 @@ def strum_matmul_pallas_maskfree_p(x, hi, scale, *, w: int, n_low: int,
     mask is what orders them), so this is the cheapest *and* lossiest
     fidelity level in the family.
     """
-    m, k_dim = x.shape
-    nb, rows, n = hi.shape
     n_high = w - n_low
     assert n_high >= 1, "maskfree_p draft needs at least one high value"
-    assert rows == n_high, (rows, n_high)
-    assert k_dim == nb * w, (k_dim, nb, w)
-    assert block_k % w == 0
-    assert m % block_m == 0 and n % block_n == 0 and k_dim % block_k == 0
-    bnb = block_k // w
-    grid = (m // block_m, n // block_n, k_dim // block_k)
-    kern = functools.partial(_kernel_maskfree_p, w=w, n_high=n_high)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bnb, rows, block_n), lambda i, j, kk: (kk, 0, j)),
-            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-        compiler_params=_mosaic_params(interpret),
-    )(x, hi, scale)
+    assert hi.shape[1] == n_high, (hi.shape, n_high)
+
+    def decode(hi_i8, scale_f32):
+        hv = hi_i8.astype(jnp.float32)                   # (bnb, n_high, bn)
+        bnb, _, bn = hv.shape
+        if n_high < w:
+            hv = jnp.concatenate(
+                [hv, jnp.zeros((bnb, w - n_high, bn), jnp.float32)], axis=1)
+        return hv.reshape(bnb * w, bn) * scale_f32
+    return _matmul_call(
+        decode, x, [hi], scale, w=w, block_m=block_m, block_n=block_n,
+        block_k=block_k, sub_k=sub_k, sub_n=sub_n, interpret=interpret)
 
 
 # --------------------------------------------------------------- grouped --

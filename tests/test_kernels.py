@@ -149,3 +149,146 @@ def test_parity_pallas_ref_dequant_grid(method, w, p, kw):
                                rtol=1e-5, atol=1e-4, err_msg=variant.name)
     np.testing.assert_allclose(np.asarray(y_deq), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-4)
+
+
+# ------------------------------------------------ blocks chosen from shapes --
+
+# (K, N) of every packed linear of the two served cells: Qwen2-7B's q/o,
+# k/v, gate/up and down, OLMo-1B's q/k/v/o, gate/up and down (w = 16,
+# MIP2Q p = 0.5 L = 5: mask, hi and lo rows 2, 8, 4)
+CELL_LINEARS = [(3584, 3584), (3584, 512), (3584, 18944), (18944, 3584),
+                (2048, 2048), (2048, 8192), (8192, 2048)]
+CELL_ROWS = (2, 8, 4)
+
+
+@pytest.mark.parametrize("m", [16, 48, 128])
+@pytest.mark.parametrize("k,n", CELL_LINEARS)
+def test_block_choice_at_cell_widths(m, k, n):
+    """Every block divides its padded axis in whole sub-tiles, the step's
+    estimated VMEM stays within the limit Mosaic is given, and an FFN
+    linear takes at most 1/16 of the grid steps of a 256 x 256 grid."""
+    from repro.kernels.strum_matmul import (STEP_WEIGHTS, VMEM_BUDGET,
+                                            VMEM_LIMIT, _vmem_bytes)
+    bm, sk, sn, bk, bn, grid = ops.grid_plan(m, k, n, 16, CELL_ROWS)
+    assert (bm, sk, sn) == (m, 256, 256)
+    assert k % bk == 0 and n % bn == 0 and bk % sk == 0 and bn % sn == 0
+    assert grid == (1, n // bn, k // bk)
+    vmem = _vmem_bytes(bm, bk, bn, 16, CELL_ROWS, 2, sk, sn)
+    assert vmem <= VMEM_BUDGET <= VMEM_LIMIT
+    steps = grid[1] * grid[2]
+    if max(k, n) in (8192, 18944):                       # the FFN linears
+        assert 16 * steps <= (k // 256) * (n // 256), (k, n, grid)
+    # the block holds at least the per-step target where the axes allow it
+    assert bk * bn >= min(STEP_WEIGHTS, k * n // 2)
+
+
+def test_block_choice_respects_budget():
+    """Where every block of ``STEP_WEIGHTS`` or more overflows
+    ``VMEM_BUDGET``, the chooser takes a smaller one that fits: a K of 101
+    sub-tiles (a prime) at 128 rows allows only whole-K blocks, over the
+    budget, or one sub-tile of K.  A small weight is one step."""
+    from repro.kernels.strum_matmul import (VMEM_BUDGET, _vmem_bytes,
+                                            block_shape)
+    k = 101 * 256
+    assert _vmem_bytes(128, k, 256, 16, CELL_ROWS, 2, 256, 256) \
+        > VMEM_BUDGET
+    bk, bn = block_shape(128, k, 3584, 16, CELL_ROWS, sub_k=256, sub_n=256)
+    assert (bk, bn) == (256, 3584)
+    assert _vmem_bytes(128, bk, bn, 16, CELL_ROWS, 2, 256, 256) \
+        <= VMEM_BUDGET
+    assert block_shape(8, 512, 256, 16, CELL_ROWS, sub_k=256,
+                       sub_n=256) == (512, 256)
+
+
+def _padded_operands(m, k, n, method, p, w, sk, sn, **kw):
+    """x and packed fields padded as ``ops._prepare`` pads them to the
+    ``(sk, sn)`` tile (K ragged to ``w`` and to ``sk``, N to ``sn``)."""
+    x, packed = _case(m, k, n, method=method, p=p, w=w, **kw)
+    k_pad = packed.mask.shape[0] * w
+    x2 = ops._pad_axis(ops._pad_axis(x, 1, k_pad), 1, sk)
+    f = {name: ops._pad_axis(ops._pad_axis(ops._min1(a, 1), 0, sk // w), 2,
+                             sn)
+         for name, a in (("mask", packed.mask), ("hi", packed.hi),
+                         ("lo", packed.lo))}
+    return x, packed, x2, f, ops._pad_axis(packed.scale, 1, sn)
+
+
+@pytest.mark.parametrize("bk,bn", [(384, 256), (128, 256), (384, 128),
+                                   (128, 128)],
+                         ids=["one_step", "n_subtiles", "k_subtiles",
+                              "one_subtile"])
+@pytest.mark.parametrize("variant", ["onehot", "maskfree", "dense"])
+def test_subtiled_kernel_bit_equal_to_tile_grid(variant, bk, bn):
+    """A (bk, bn) block folded in 128 x 128 sub-tiles gives the bits of a
+    grid of 128 x 128 tiles: the same decode, the same f32 dots, K summed
+    in the same order.  K = 300 is ragged to w = 16 and to the tile, N =
+    200 pads to two tiles, so padded rows and columns pass through."""
+    from repro.kernels.strum_matmul import (strum_matmul_pallas,
+                                            strum_matmul_pallas_dense,
+                                            strum_matmul_pallas_maskfree)
+    method, p, kw = {"onehot": ("mip2q", 0.5, {"L": 5}),
+                     "maskfree": ("dliq", 1.0, {"q": 4}),
+                     "dense": ("dliq", 0.0, {"q": 4})}[variant]
+    m, sk, sn = 8, 128, 128
+    x, packed, x2, f, scale = _padded_operands(m, 300, 200, method, p, 16,
+                                               sk, sn, **kw)
+    assert x2.shape == (m, 384) and scale.shape == (1, 256)
+
+    def run(block_k, block_n):
+        tiles = dict(block_m=m, block_k=block_k, block_n=block_n, sub_k=sk,
+                     sub_n=sn, interpret=True)
+        if variant == "onehot":
+            return strum_matmul_pallas(
+                x2, f["mask"], f["hi"], f["lo"], scale, w=16,
+                n_low=packed.n_low, q=packed.q, method=method, **tiles)
+        if variant == "maskfree":
+            return strum_matmul_pallas_maskfree(
+                x2, f["lo"], scale, w=16, q=packed.q, method=method, **tiles)
+        return strum_matmul_pallas_dense(x2, f["hi"], scale, w=16, **tiles)
+
+    y, y_tiles = np.asarray(run(bk, bn)), np.asarray(run(sk, sn))
+    np.testing.assert_array_equal(y, y_tiles)
+    np.testing.assert_allclose(y[:, :200],
+                               np.asarray(ref.strum_matmul_ref(x, packed)),
+                               rtol=1e-5, atol=1e-4)
+
+
+def test_strum_matmul_one_step_of_many_subtiles():
+    """Through the public wrapper: a 1024 x 512 weight is one grid step of
+    eight 256 x 256 sub-tiles, bit-equal to the 256 x 256 tile grid."""
+    from repro.kernels.strum_matmul import strum_matmul_pallas
+    x, packed = _case(4, 1024, 512, method="mip2q", p=0.5, L=5)
+    *_, bk, bn, grid = ops.grid_plan(4, 1024, 512, 16, CELL_ROWS)
+    assert (bk, bn, grid) == (1024, 512, (1, 1, 1))
+    y = ops.strum_matmul(x, packed, interpret=True)
+    x8 = ops._pad_axis(x, 0, 8)
+    y_tiles = strum_matmul_pallas(
+        x8, packed.mask, packed.hi, packed.lo, packed.scale, w=16,
+        n_low=packed.n_low, q=packed.q, method="mip2q", block_m=8,
+        block_n=256, block_k=256, interpret=True)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_tiles)[:4])
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ref.strum_matmul_ref(x, packed)),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("mode", ["histream", "maskfree_p"])
+def test_draft_one_step_of_many_subtiles(mode):
+    """The draft lowerings take the same shape-chosen block: a 1024 x 512
+    weight is one grid step of eight 256 x 256 sub-tiles, bit-equal to the
+    draft kernel over the 256 x 256 tile grid."""
+    from repro.kernels.strum_matmul import (strum_matmul_pallas_histream,
+                                            strum_matmul_pallas_maskfree_p)
+    x, packed = _case(4, 1024, 512, method="mip2q", p=0.5, L=5)
+    assert ops.grid_steps(4, packed, mode) == 1
+    y = ops.strum_matmul_draft(x, packed, mode=mode, interpret=True)
+    x8 = ops._pad_axis(x, 0, 8)
+    tiles = dict(w=16, n_low=packed.n_low, block_m=8, block_n=256,
+                 block_k=256, interpret=True)
+    if mode == "histream":
+        y_tiles = strum_matmul_pallas_histream(
+            x8, packed.mask, packed.hi, packed.scale, **tiles)
+    else:
+        y_tiles = strum_matmul_pallas_maskfree_p(
+            x8, packed.hi, packed.scale, **tiles)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_tiles)[:4])

@@ -124,6 +124,25 @@ def test_dispatch_counters_match_plan_distribution(setup):
     assert rec.counter("dispatch/sharded/gathered_packed_bytes") == 0
 
 
+def test_dispatch_counts_grid_steps_of_packed_calls():
+    """``dispatch/grid_steps`` holds the grid steps of each 2-D Pallas call
+    at trace time: a 1024 x 4096 MIP2Q weight over 8 rows is four 1024 x
+    1024 blocks (64 steps of a 256 x 256 grid); the XLA fallback counts
+    none."""
+    import jax
+    from repro.core.apply import pack_array
+    cfg = StruMConfig(method="mip2q", p=0.5, L=5)
+    p = pack_array(jnp.asarray(RNG.normal(size=(1024, 4096))
+                               .astype(np.float32)), cfg)
+    leaf = {"mask": p.mask, "hi": p.hi, "lo": p.lo, "scale": p.scale}
+    x = jax.ShapeDtypeStruct((8, 1024), jnp.bfloat16)
+    for backend, want in (("interpret", 4), ("xla", 0)):
+        with telemetry.recording() as rec:
+            jax.make_jaxpr(lambda x: engine.dispatch(
+                leaf, x, strum=cfg, backend=backend))(x)
+        assert rec.counter("dispatch/grid_steps") == want, backend
+
+
 # --------------------------------------------- scheduler lifecycle streams
 
 def test_scheduler_lifecycle_well_ordered(setup):

@@ -58,7 +58,8 @@ def _packed_shapes(cfg, k, n, chip):
     ("onehot", MIXED), ("maskfree", LOW_ONLY), ("dense", HIGH_ONLY)])
 @pytest.mark.parametrize("k,n", [(2048, 8192), (8192, 2048)])
 def test_gemv_compiles_at_olmo_widths(chip, variant, cfg, k, n):
-    """Decode GEMV tiles (M=8) of the MLP in and out projections."""
+    """Decode GEMVs (M=8) of the MLP in and out projections, at the blocks
+    ``ops.grid_plan`` chooses from their shapes."""
     def gemv(x, mask, hi, lo, scale):
         p = packing.PackedStruM(cfg.method, cfg.w, cfg.n_low, cfg.q, cfg.L,
                                 k, scale, mask, hi, lo)
@@ -77,6 +78,28 @@ def test_onehot_compiles_at_prefill_tile(chip):
         return ops.strum_matmul(x, p, interpret=False, variant="onehot")
     _compile(matmul,
              jax.ShapeDtypeStruct((128, k), jnp.bfloat16, sharding=chip),
+             *_packed_shapes(MIXED, k, n, chip))
+
+
+@pytest.mark.parametrize("m", [48, 128], ids=["decode", "prefill_chunk"])
+@pytest.mark.parametrize("k,n", [(3584, 3584), (3584, 512), (3584, 18944),
+                                 (18944, 3584)],
+                         ids=["q_o", "k_v", "gate_up", "down"])
+def test_onehot_compiles_at_qwen2_chosen_blocks(chip, m, k, n):
+    """pallas:onehot at Qwen2-7B's linear shapes, over its 48 decode rows and
+    a 128-token chunk, at the multi-sub-tile blocks the chooser picks: the
+    fold's dynamic sub-tile slices lower, and the step fits the VMEM limit
+    the kernel gives Mosaic."""
+    mb, nh, lb = packing.field_dims(MIXED.w, MIXED.n_low, MIXED.q,
+                                    MIXED.method)
+    *_, bk, bn, _ = ops.grid_plan(m, k, n, MIXED.w, (mb, nh, lb))
+    assert bk * bn > 256 * 256, (bk, bn)
+
+    def matmul(x, mask, hi, lo, scale):
+        p = packing.PackedStruM(MIXED.method, MIXED.w, MIXED.n_low, MIXED.q,
+                                MIXED.L, k, scale, mask, hi, lo)
+        return ops.strum_matmul(x, p, interpret=False, variant="onehot")
+    _compile(matmul, jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=chip),
              *_packed_shapes(MIXED, k, n, chip))
 
 
